@@ -1073,6 +1073,8 @@ def main() -> None:
     ap.add_argument("--out", default="BENCH_census.json",
                     help="device-pipeline JSON output path")
     args = ap.parse_args()
+    from repro.engine.config import use_compile_cache
+    use_compile_cache()
 
     def device_pipeline(scale):
         bench_device_pipeline(scale, sync_baseline=args.sync_baseline,

@@ -26,7 +26,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .. import compat
 from .census import make_census_batch_fn
 from .graph import CSRGraph
 
@@ -65,7 +64,8 @@ def make_census_fn_for_mesh(mesh: jax.sharding.Mesh, *, K: int | None = None,
             uu, vv, va = xs
             return carry + batch_fn(arrays, n, uu, vv, va), None
 
-        init = compat.pvary(jnp.zeros((n_bins,), acc_dtype), axes)
+        init = jax.lax.pcast(jnp.zeros((n_bins,), acc_dtype), axes,
+                             to="varying")
         counts, _ = jax.lax.scan(
             step, init,
             (u.reshape(steps, batch), v.reshape(steps, batch),
@@ -76,11 +76,12 @@ def make_census_fn_for_mesh(mesh: jax.sharding.Mesh, *, K: int | None = None,
             counts = jax.lax.psum(counts, ax)
         return counts
 
-    shmap = compat.shard_map(
+    shmap = jax.shard_map(
         device_census,
         mesh=mesh,
         in_specs=(P(), P(), P(axes), P(axes), P(axes)),
         out_specs=P(),
+        check_vma=False,
     )
     return jax.jit(shmap)
 

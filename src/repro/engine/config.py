@@ -14,6 +14,8 @@ buckets and the op names).
 from __future__ import annotations
 
 import dataclasses
+import os
+import pathlib
 from typing import Optional, Tuple
 
 import jax
@@ -39,8 +41,10 @@ class EngineConfig:
             ``"auto"`` (resolved from the visible hardware at compile time).
         batch: dyads per scan step (xla/distributed backends).
         block: pallas kernel block (dyads per grid step).  ``None`` picks
-            ``min(batch, 32)`` — the (block, K, K) membership-compare
-            intermediate makes large blocks expensive.
+            ``min(batch, 32)`` — the kernel's VMEM holds six
+            double-buffered ``(block, K)`` tiles plus three ``(block, K)``
+            accumulators, about 15 MiB at ``(32, 8192)``.  On a TPU the
+            block must be a multiple of 8.
         k: tile width override (candidate lanes per dyad).  ``None`` derives
             a power-of-two bucket from the graph's max degree so same-shape
             graphs share one compiled plan.
@@ -385,6 +389,23 @@ class EngineConfig:
 
     def resolve_block(self) -> int:
         return self.block if self.block is not None else min(self.batch, 32)
+
+
+def use_compile_cache() -> str:
+    """Point JAX's persistent compilation cache at a fixed place.
+
+    Call once at the start of a program, before its first compile (never
+    from module import).  When ``JAX_COMPILATION_CACHE_DIR`` is set, JAX
+    already reads it and nothing is changed here; otherwise the cache is
+    ``<checkout>/.jax_cache`` — a fixed path, since the path is part of
+    what makes a cache entry found again.  Returns the directory in use.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(pathlib.Path(__file__).resolve().parents[3] / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 #: Census-era name for :class:`EngineConfig` — the same class (not a

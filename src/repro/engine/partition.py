@@ -595,7 +595,6 @@ def _mesh_unit(plan):
         return plan._mesh_part_fn
     from jax.sharding import PartitionSpec as P
 
-    from .. import compat
     from .executor import _acc_update
     mesh = plan.mesh
     axes = tuple(mesh.axis_names)
@@ -621,10 +620,10 @@ def _mesh_unit(plan):
         return h[None], l[None]
 
     sh = P(axes)
-    unit = jax.jit(compat.shard_map(
+    unit = jax.jit(jax.shard_map(
         device_pass, mesh=mesh,
         in_specs=(sh, P(), sh, sh, sh, sh, sh),
-        out_specs=(sh, sh)))
+        out_specs=(sh, sh), check_vma=False))
     plan._mesh_part_fn = unit
     return unit
 

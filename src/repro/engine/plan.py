@@ -28,6 +28,7 @@ import collections
 import dataclasses
 import functools
 import math
+import warnings
 import weakref
 from typing import Optional
 
@@ -240,11 +241,13 @@ class Plan:
     def _demote(self, to: str, *, stage: str, reason: str) -> None:
         """One rung of the degradation ladder: permanently re-point this
         plan at backend ``to`` (rebuilding its compiled unit), record the
-        event in ``degradation`` / ``stats``, and keep serving.  The xla
-        unit computes the same fused integer bins, so demoted results
-        stay bit-identical; chunk-schedule memo entries are keyed by
-        backend kind and cannot leak across the demotion."""
+        event in ``degradation`` / ``stats``, warn with the reason, and
+        keep serving.  The xla unit computes the same fused integer bins,
+        so demoted results stay bit-identical; chunk-schedule memo entries
+        are keyed by backend kind and cannot leak across the demotion."""
         frm = self.backend
+        warnings.warn(f"{frm} backend demoted to {to} at {stage}: {reason}",
+                      RuntimeWarning, stacklevel=3)
         self.backend = to
         self.executor.backend = to
         self._fn = self._build_fn(to)

@@ -33,7 +33,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from .. import compat
 from ..config.base import ModelConfig
 from ..sharding.rules import batch_axes
 from .moe import _positions_in_expert
@@ -96,7 +95,7 @@ def make_expert_parallel_moe(cfg: ModelConfig, mesh):
         y_all = jax.lax.all_gather(y, "model", axis=0, tiled=True)
         return y_all.reshape(Bl, T, d)
 
-    shmap = compat.shard_map(
+    shmap = jax.shard_map(
         local_moe,
         mesh=mesh,
         in_specs=(P(b_axes, None, None), P(None, None),

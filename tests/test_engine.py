@@ -143,3 +143,29 @@ print('OK')
                        capture_output=True, text=True, timeout=600)
     assert r.returncode == 0, r.stderr[-2000:]
     assert "OK" in r.stdout
+
+
+@pytest.mark.parametrize("env_dir", [None, "from-env"])
+def test_compile_cache_location(monkeypatch, tmp_path, env_dir):
+    """An explicit JAX_COMPILATION_CACHE_DIR is left to JAX; otherwise the
+    cache is the fixed <checkout>/.jax_cache."""
+    import jax
+
+    from repro.engine.config import use_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    if env_dir:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        got = use_compile_cache()
+        if env_dir:
+            assert got == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == before
+        else:
+            checkout = os.path.dirname(os.path.dirname(os.path.abspath(
+                __file__)))
+            assert got == os.path.join(checkout, ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == got
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

@@ -222,7 +222,9 @@ def test_pallas_compile_failure_demotes_to_xla():
     want = brute_force_census(g).counts
     cfg = EngineConfig(backend="pallas", batch=16, chunk_dyads=64,
                        fault_plan=FaultPlan(compile_failure=("pallas",)))
-    plan = compile(g, ("triad_census",), cfg)
+    with pytest.warns(RuntimeWarning,
+                      match="pallas backend demoted to xla at compile"):
+        plan = compile(g, ("triad_census",), cfg)
     assert plan.requested_backend == "pallas"
     assert plan.backend == "xla"  # demoted at build time
     assert plan.degradation and plan.degradation[0]["rung"] == "pallas->xla"
@@ -243,7 +245,9 @@ def test_pallas_runtime_failure_demotes_to_xla():
                        fault_plan=FaultPlan(runtime_failure=("pallas",)))
     plan = compile(g, ("triad_census",), cfg)
     assert plan.backend == "pallas"  # compiles fine, fails at dispatch
-    res = plan.run(g)
+    with pytest.warns(RuntimeWarning,
+                      match="pallas backend demoted to xla at runtime"):
+        res = plan.run(g)
     assert plan.backend == "xla"
     assert np.array_equal(res["triad_census"].counts, want)
     assert plan.degradation[0]["stage"] == "runtime"

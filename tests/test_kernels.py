@@ -22,21 +22,24 @@ def test_census_kernel_matches_brute_force(seed, block, buckets):
     assert (got == want).all(), (got, want)
 
 
-def test_census_kernel_matches_tile_oracle():
-    """Kernel vs ref.census_tiles_ref on identical random tiles."""
+@pytest.mark.parametrize("width", [None, 128, 200, 256])
+def test_census_kernel_matches_tile_oracle(width):
+    """Kernel vs ref.census_tiles_ref on identical random tiles, at the
+    graph's own tile width (below one 128-lane window), one full window,
+    a width the kernel pads up to 256, and two full windows."""
     g = generators.erdos_renyi(60, 240, seed=3)
     from repro.core.census import canonical_dyads
     u, v = canonical_dyads(g)
     D = (len(u) // 16) * 16
     u, v = u[:D].astype(np.int32), v[:D].astype(np.int32)
-    K = max(g.max_deg, g.max_out_deg)
+    K = width or max(g.max_deg, g.max_out_deg)
     tiles = ops.build_tiles(g, u.astype(np.int64), v.astype(np.int64), K)
     args = [jnp.asarray(tiles[k]) for k in
             ("out_u", "in_u", "out_v", "in_v", "nbr_u", "nbr_v")]
     want = ref.census_tiles_ref(*args, jnp.asarray(u), jnp.asarray(v), g.n)
     # oracle takes (out_u, in_u, ... , u, v, n) in different arg order
     got = census_tiles_pallas(jnp.asarray(u), jnp.asarray(v), g.n, *args,
-                              block=16)
+                              block=16, interpret=True)
     assert (np.asarray(got) == np.asarray(want)).all()
 
 

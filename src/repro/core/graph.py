@@ -23,6 +23,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .spans import span
+
 
 def next_pow2(x: int) -> int:
     """Smallest power of two >= x (minimum 1) — the metadata bucket and
@@ -121,15 +123,16 @@ def from_edges(n: int, src, dst, *, directed: bool = True) -> CSRGraph:
     duplicate arcs are deduplicated, as in the paper's pre-processing stage.
     For ``directed=False`` every edge is materialized as a mutual dyad.
     """
-    host, m, m_nbr, max_deg, max_out_deg = _build_host_arrays(
-        n, src, dst, directed=directed)
-    arrays = GraphArrays(
-        out_ptr=jnp.asarray(host.out_ptr),
-        out_idx=jnp.asarray(host.out_idx),
-        nbr_ptr=jnp.asarray(host.nbr_ptr),
-        nbr_idx=jnp.asarray(host.nbr_idx),
-        nbr_deg=jnp.asarray(host.nbr_deg),
-    )
+    with span("from_edges"):
+        host, m, m_nbr, max_deg, max_out_deg = _build_host_arrays(
+            n, src, dst, directed=directed)
+        arrays = GraphArrays(
+            out_ptr=jnp.asarray(host.out_ptr),
+            out_idx=jnp.asarray(host.out_idx),
+            nbr_ptr=jnp.asarray(host.nbr_ptr),
+            nbr_idx=jnp.asarray(host.nbr_idx),
+            nbr_deg=jnp.asarray(host.nbr_deg),
+        )
     return CSRGraph(n=n, m=m, m_nbr=m_nbr, max_deg=max_deg,
                     max_out_deg=max_out_deg, arrays=arrays)
 

@@ -63,6 +63,7 @@ from ..core.census import (canonical_dyads, enumerate_dyads_device,
                            sort_dyads_by_bucket)
 from ..core.distributed import make_census_fn_for_mesh
 from ..core.graph import CSRGraph, next_pow2
+from ..core.spans import span
 from .executor import ChunkTask, _acc_fetch, _acc_update
 
 
@@ -243,9 +244,12 @@ def _memo_tasks(plan, g: CSRGraph, key, build) -> "list[ChunkTask]":
     """
     full_key = (key, id(g))
     hit = plan._task_memo.get(full_key)
-    if hit is not None and hit[0]() is g:
-        return hit[1]
-    tasks = build()
+    fresh = hit is not None and hit[0]() is g
+    plan.stats["task_memo_hits" if fresh else "task_memo_misses"] += 1
+    with span("schedule", hit=fresh):
+        if fresh:
+            return hit[1]
+        tasks = build()
     while len(plan._task_memo) >= 8:
         plan._task_memo.pop(next(iter(plan._task_memo)))
     plan._task_memo[full_key] = (weakref.ref(g), tasks)
@@ -307,9 +311,10 @@ def run_xla(plan, g: CSRGraph) -> np.ndarray:
     if g.n_dyads == 0:
         return np.zeros(plan.layout.total_bins, dtype=np.int64)
     arrays = plan.padded_arrays(g)
-    du, dv = enumerate_dyads_device(arrays.nbr_ptr, arrays.nbr_idx,
-                                    jnp.int32(g.m_nbr),
-                                    out_size=plan.dyad_pad)
+    with span("enumerate"):
+        du, dv = enumerate_dyads_device(arrays.nbr_ptr, arrays.nbr_idx,
+                                        jnp.int32(g.m_nbr),
+                                        out_size=plan.dyad_pad)
     n = jnp.int32(g.n)
     hi = lo = jnp.zeros(plan.layout.total_bins, jnp.int32)
     init = _once_device(plan, hi, lo, arrays, n)
@@ -490,7 +495,7 @@ def run_distributed(plan, g: CSRGraph) -> np.ndarray:
 # ----------------------------------------------------------------------------
 
 
-def make_pallas_chunk_fn(layout, config):
+def make_pallas_chunk_fn(layout, config, stats: dict):
     """Fused device chunk unit for the pallas backend.
 
     ``(arrays, n, su, sv, start, end, hi, lo; K, chunk, block, interpret)``:
@@ -500,7 +505,8 @@ def make_pallas_chunk_fn(layout, config):
     — one dispatch, zero host staging (per-run ``once`` contributions are
     folded by the driver before the chunk loop).  Ops other than the
     census don't need the tiles, so the one expensive gather is paid
-    exactly once per chunk for the whole op set.
+    exactly once per chunk for the whole op set.  ``stats['traces']``
+    counts its traces, one per tile width ``K`` on a warm plan.
     """
     from ..kernels import ops as kops
     from ..kernels.triad_census import SENTINEL, census_tiles_pallas
@@ -514,6 +520,7 @@ def make_pallas_chunk_fn(layout, config):
                        static_argnames=("K", "chunk", "block", "interpret"))
     def pallas_chunk(arrays, n, su, sv, start, end, hi, lo, *, K: int,
                      chunk: int, block: int, interpret: bool):
+        stats["traces"] += 1
         pos = start + jnp.arange(chunk, dtype=jnp.int32)
         valid = pos < end
         u = jnp.take(su, pos, mode="clip")
@@ -636,20 +643,22 @@ def run_pallas(plan, g: CSRGraph) -> np.ndarray:
     # exists for the census slice; a plan of generic ops skips all three.
     census_needed = "triad_census" in plan.layout.slices
     arrays = plan.padded_arrays(g, with_in_csr=census_needed)
-    du, dv = enumerate_dyads_device(arrays.nbr_ptr, arrays.nbr_idx,
-                                    jnp.int32(g.m_nbr),
-                                    out_size=plan.dyad_pad)
+    with span("enumerate"):
+        du, dv = enumerate_dyads_device(arrays.nbr_ptr, arrays.nbr_idx,
+                                        jnp.int32(g.m_nbr),
+                                        out_size=plan.dyad_pad)
+        stream_u, stream_v = du, dv
+        if census_needed:
+            stream_u, stream_v, _ = sort_dyads_by_bucket(
+                arrays.nbr_deg, arrays.out_ptr, du, dv,
+                jnp.int32(g.n_dyads), ks=ks)
     n = jnp.int32(g.n)
     hi = lo = jnp.zeros(plan.layout.total_bins, jnp.int32)
     init = _once_device(plan, hi, lo, arrays, n)
     if not census_needed:
-        stream_u, stream_v = du, dv
         tasks = [t._replace(key=kmax)
                  for t in _dyad_tasks(plan, g, chunk=chunk)]
     else:
-        stream_u, stream_v, _ = sort_dyads_by_bucket(
-            arrays.nbr_deg, arrays.out_ptr, du, dv, jnp.int32(g.n_dyads),
-            ks=ks)
         # the per-bucket schedule used to be a device→host control fetch
         # of the sort's bucket counts — the extra counted sync the other
         # backends never paid, and it stalled dispatch until the device
@@ -657,6 +666,7 @@ def run_pallas(plan, g: CSRGraph) -> np.ndarray:
         # arrays the host already owns, so derive them (and the per-dyad
         # tile-width needs, the dynamic schedule's cost model) host-side.
         tasks = _pallas_bucket_tasks(plan, g, ks, chunk)
+        count_tiles(plan.stats, tasks, chunk)
 
     def place(dev):
         ctx = (arrays, n, stream_u, stream_v)
@@ -670,6 +680,15 @@ def run_pallas(plan, g: CSRGraph) -> np.ndarray:
 
     hi, lo = plan.executor.run(tasks, place=place, step=step, init=init)
     return _acc_fetch(plan, hi, lo)
+
+
+def count_tiles(stats: dict, tasks, chunk: int) -> None:
+    """Count a census pass's tile work in ``stats``: ``tile_slots``, the
+    slots of the six ``(chunk, K)`` tiles each task gathers (padding
+    included), and ``dyads``, the live dyads those tiles hold."""
+    stats["tile_slots"] += sum(6 * chunk * t.key for t in tasks)
+    stats["dyads"] += sum(min(t.end, t.start + chunk) - t.start
+                          for t in tasks)
 
 
 def _pallas_bucket_tasks(plan, g: CSRGraph, ks: tuple,

@@ -266,6 +266,8 @@ def _pallas_subset_schedule(plan, g: CSRGraph, u: np.ndarray, v: np.ndarray):
                                     float(K * min(chunk, offset + c - s)), K)
                           for s in range(offset, offset + c, chunk)]
             offset += c
+        from .backends import count_tiles
+        count_tiles(plan.stats, tasks, chunk)
     else:
         tasks = [t._replace(key=kmax)
                  for t in _subset_tasks(plan, g, u, v, chunk)]

@@ -67,6 +67,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..core.spans import span
 from .faults import DeviceLostError, InjectedFault, resolve_faults
 
 # the device accumulator is an int32 (hi, lo) pair: count = hi * 2**30 + lo
@@ -90,7 +91,8 @@ def _acc_update(hi, lo, delta):
 def _acc_fetch(plan, hi, lo) -> np.ndarray:
     """THE device→host transfer of a device-resident run (counted)."""
     plan.stats["host_syncs"] += 1
-    packed = np.asarray(jnp.stack([hi, lo]), dtype=np.int64)
+    with span("fetch"):
+        packed = np.asarray(jnp.stack([hi, lo]), dtype=np.int64)
     return (packed[0] << _ACC_SHIFT) + packed[1]
 
 
@@ -113,7 +115,8 @@ def _throttle(window: collections.deque, ref, depth: int) -> None:
     """
     window.append(ref)
     if len(window) > max(1, depth):
-        window.popleft().block_until_ready()
+        with span("wait"):
+            window.popleft().block_until_ready()
 
 
 class WorkerFailures(RuntimeError):
@@ -268,7 +271,9 @@ class Executor:
                     f"injected failure for chunk at dyad {task.start} "
                     f"(attempt {attempt})")
             f.maybe_delay(task.start)
-        return step(ctx, hi, lo, task)
+        args = {} if task.key is None else {"K": task.key}
+        with span("chunk", start=task.start, end=task.end, **args):
+            return step(ctx, hi, lo, task)
 
     def _attempt(self, ctx, hi, lo, task, step, dev_index, ordinal):
         """Bounded-retry dispatch of one task on one device (the static

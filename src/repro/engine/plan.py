@@ -40,6 +40,7 @@ from ..core.census import CensusResult
 from ..core.graph import CSRGraph, GraphArrays
 from ..core.graph import next_pow2 as _next_pow2
 from ..core.reorder import compute_permutation, permute_graph
+from ..core.spans import span
 from . import backends
 from .config import EngineConfig
 from .executor import Executor
@@ -164,6 +165,8 @@ class Plan:
         self.stats = {"traces": 0, "runs": 0, "chunks": 0, "host_syncs": 0,
                       "batch_runs": 0, "batch_graphs": 0, "device_chunks": {},
                       "delta_runs": 0, "delta_fulls": 0, "reorders": 0,
+                      "tile_slots": 0, "dyads": 0, "bytes_staged": 0,
+                      "task_memo_hits": 0, "task_memo_misses": 0,
                       "faults": dict(chunk_failures=0, retries=0,
                                      device_losses=0, quarantines=0,
                                      backend_fallbacks=0,
@@ -235,7 +238,8 @@ class Plan:
             return make(self.layout, config, self.mesh, self.stats)
         if backend == "pallas":
             # fused chunk unit; pallas_call manages its own per-shape cache
-            return backends.make_pallas_chunk_fn(self.layout, config)
+            return backends.make_pallas_chunk_fn(self.layout, config,
+                                                 self.stats)
         raise ValueError(f"unknown backend {backend!r}")
 
     def _demote(self, to: str, *, stage: str, reason: str) -> None:
@@ -307,18 +311,21 @@ class Plan:
         path when an op actually uses the census tile kernel, the one
         consumer of in-arc tiles.
         """
-        host = self.padded_arrays_host(g)
-        arrays = GraphArrays(
-            **{f: (None if v is None else jnp.asarray(v))
-               for f, v in zip(GraphArrays._fields, host)})
-        if with_in_csr is None:
-            with_in_csr = (self.backend == "pallas" and self.device_path
-                           and "triad_census" in self.layout.slices)
-        if with_in_csr:
-            from ..kernels import ops
-            in_ptr, in_idx = ops.build_in_csr_device(arrays.out_ptr,
-                                                     arrays.out_idx)
-            arrays = arrays._replace(in_ptr=in_ptr, in_idx=in_idx)
+        with span("stage"):
+            host = self.padded_arrays_host(g)
+            self.stats["bytes_staged"] += sum(v.nbytes for v in host
+                                              if v is not None)
+            arrays = GraphArrays(
+                **{f: (None if v is None else jnp.asarray(v))
+                   for f, v in zip(GraphArrays._fields, host)})
+            if with_in_csr is None:
+                with_in_csr = (self.backend == "pallas" and self.device_path
+                               and "triad_census" in self.layout.slices)
+            if with_in_csr:
+                from ..kernels import ops
+                in_ptr, in_idx = ops.build_in_csr_device(arrays.out_ptr,
+                                                         arrays.out_idx)
+                arrays = arrays._replace(in_ptr=in_ptr, in_idx=in_idx)
         return arrays
 
     # -- locality-aware reordering -------------------------------------------
@@ -381,7 +388,9 @@ class Plan:
         through the single-graph (un-vmapped) units, which produce
         bit-identical raw bins — every op is pure integer arithmetic.
         """
-        return self.layout.finalize(self.run_raw(g), g)
+        raw = self.run_raw(g)
+        with span("finalize"):
+            return self.layout.finalize(raw, g)
 
     def run_raw(self, g: CSRGraph) -> np.ndarray:
         """Execute the fused pass and return the raw int64 accumulator bins
@@ -396,7 +405,8 @@ class Plan:
         check_poisoned(g)
         self._check(g)
         self.stats["runs"] += 1
-        return self._execute_raw(g)
+        with span("run", run=self.stats["runs"]):
+            return self._execute_raw(g)
 
     def apply_delta(self, g: CSRGraph, delta, raw=None) -> "DeltaResult":
         """Advance a census stream by one mutation batch — work
@@ -421,7 +431,8 @@ class Plan:
         from .delta import run_delta
         self._check(g)
         self.stats["runs"] += 1
-        return run_delta(self, g, delta, raw)
+        with span("run", run=self.stats["runs"]):
+            return run_delta(self, g, delta, raw)
 
     def _run_raw(self, g: CSRGraph) -> np.ndarray:
         """Backend dispatch: the fused raw int64 bins (no finalize).
@@ -483,19 +494,23 @@ class Plan:
         self.stats["runs"] += len(graphs)
         self.stats["batch_runs"] += 1
         self.stats["batch_graphs"] += len(graphs)
-        if self.backend == "xla" and self.device_path and self.partitions == 1:
-            # reorder each member (memoized) and batch the relabeled
-            # graphs — same buckets, so the vmapped unit is unchanged;
-            # raw bins map back per member before finalize.  Partitioned
-            # plans take the member-wise branch below: each member runs
-            # the sharded path with its own bounded shard contexts.
-            pairs = [self._reordered(g) for g in graphs]
-            raws = backends.run_xla_batch(self, [ge for ge, _ in pairs])
-            return [self.layout.finalize(
-                        raw if perm is None
-                        else self.layout.unpermute(raw, perm, g), g)
-                    for raw, (_, perm), g in zip(raws, pairs, graphs)]
-        return [self.layout.finalize(self._execute_raw(g), g) for g in graphs]
+        with span("run", run=self.stats["runs"]):
+            if (self.backend == "xla" and self.device_path
+                    and self.partitions == 1):
+                # reorder each member (memoized) and batch the relabeled
+                # graphs — same buckets, so the vmapped unit is unchanged;
+                # raw bins map back per member before finalize.
+                # Partitioned plans take the member-wise branch below:
+                # each member runs the sharded path with its own bounded
+                # shard contexts.
+                pairs = [self._reordered(g) for g in graphs]
+                raws = backends.run_xla_batch(self, [ge for ge, _ in pairs])
+                return [self.layout.finalize(
+                            raw if perm is None
+                            else self.layout.unpermute(raw, perm, g), g)
+                        for raw, (_, perm), g in zip(raws, pairs, graphs)]
+            return [self.layout.finalize(self._execute_raw(g), g)
+                    for g in graphs]
 
     def batch_fn(self):
         """The vmapped batched unit (xla device path), built lazily.
@@ -669,41 +684,44 @@ def compile(graph_meta, ops=("triad_census",),
     trace — and a census-only ``compile_census`` call shares the same
     entry as ``compile(graph, ("triad_census",), config)``.
     """
-    config = config or EngineConfig()
-    op_objs = resolve_ops(ops)
-    meta = (graph_meta if isinstance(graph_meta, GraphMeta)
-            else GraphMeta.from_graph(graph_meta, k=config.k))
-    backend = config.resolve_backend()
-    # normalize: an "auto" config and the explicit backend it resolves to
-    # must share one cache entry (and one compiled plan); likewise
-    # device_accum=None and the True it resolves to, and the executor
-    # pool width None/over-asked resolves to (1 under the static schedule
-    # and on the distributed backend, whose mesh owns every device).
-    config = dataclasses.replace(
-        config, backend=backend,
-        device_accum=config.resolve_device_accum(),
-        n_executor_devices=(1 if backend == "distributed"
-                            else config.resolve_executor_devices()),
-        partitions=config.resolve_partitions(),
-        spill=config.resolve_spill(),
-        partition_mode=config.resolve_partition_mode(backend))
-    if backend == "distributed" and mesh is None:
-        mesh = _default_mesh(len(jax.devices()))
-    # key on the op *instances* (identity), not their names: re-registering
-    # an op (overwrite=True) or passing an unregistered instance whose name
-    # collides with a built-in must compile fresh, never reuse a plan built
-    # against a different implementation.
-    key = (meta, op_objs, config, mesh)
-    plan = _PLAN_CACHE.get(key)
-    if plan is not None:
-        _CACHE_STATS["hits"] += 1
-        _PLAN_CACHE.move_to_end(key)  # LRU freshness
+    with span("compile") as sp:
+        config = config or EngineConfig()
+        op_objs = resolve_ops(ops)
+        meta = (graph_meta if isinstance(graph_meta, GraphMeta)
+                else GraphMeta.from_graph(graph_meta, k=config.k))
+        backend = config.resolve_backend()
+        # normalize: an "auto" config and the explicit backend it resolves
+        # to must share one cache entry (and one compiled plan); likewise
+        # device_accum=None and the True it resolves to, and the executor
+        # pool width None/over-asked resolves to (1 under the static
+        # schedule and on the distributed backend, whose mesh owns every
+        # device).
+        config = dataclasses.replace(
+            config, backend=backend,
+            device_accum=config.resolve_device_accum(),
+            n_executor_devices=(1 if backend == "distributed"
+                                else config.resolve_executor_devices()),
+            partitions=config.resolve_partitions(),
+            spill=config.resolve_spill(),
+            partition_mode=config.resolve_partition_mode(backend))
+        if backend == "distributed" and mesh is None:
+            mesh = _default_mesh(len(jax.devices()))
+        # key on the op *instances* (identity), not their names:
+        # re-registering an op (overwrite=True) or passing an unregistered
+        # instance whose name collides with a built-in must compile fresh,
+        # never reuse a plan built against a different implementation.
+        key = (meta, op_objs, config, mesh)
+        plan = _PLAN_CACHE.get(key)
+        sp.set_metadata(hit=plan is not None)
+        if plan is not None:
+            _CACHE_STATS["hits"] += 1
+            _PLAN_CACHE.move_to_end(key)  # LRU freshness
+            return plan
+        _CACHE_STATS["misses"] += 1
+        plan = Plan(meta, op_objs, config, backend, mesh)
+        _PLAN_CACHE[key] = plan
+        _evict_to_capacity()
         return plan
-    _CACHE_STATS["misses"] += 1
-    plan = Plan(meta, op_objs, config, backend, mesh)
-    _PLAN_CACHE[key] = plan
-    _evict_to_capacity()
-    return plan
 
 
 def compile_census(graph_meta, config: Optional[EngineConfig] = None, *,

@@ -1,0 +1,120 @@
+"""The engine's spans and counters: a small census on the pallas path
+(interpret mode on CPU) under ``jax.profiler.trace`` opens every
+``repro.*`` span, nested as documented, and its counters agree with the
+plan's own chunk schedule."""
+import dataclasses
+import glob
+
+import numpy as np
+import pytest
+
+import jax
+from repro.core import generators
+from repro.core.graph import arcs_host, from_edges
+from repro.engine import EngineConfig, compile
+
+# small chunks, so one census dispatches more chunks than the pipeline
+# depth and ``repro.wait`` opens too
+CONFIG = EngineConfig(backend="pallas", batch=32, chunk_dyads=64,
+                      buckets=(4, 8))
+
+# span -> the span it opens inside (None: outside every repro.* span)
+NESTING = {"repro.from_edges": None, "repro.compile": None,
+           "repro.run": None, "repro.stage": "repro.run",
+           "repro.enumerate": "repro.run", "repro.schedule": "repro.run",
+           "repro.chunk": "repro.run", "repro.wait": "repro.run",
+           "repro.fetch": "repro.run", "repro.finalize": None}
+
+
+def _arcs():
+    g = generators.rmat(6, edge_factor=4, seed=3)
+    return (g.n, *arcs_host(g))
+
+
+def _census(arcs, config=CONFIG):
+    g = from_edges(*arcs)
+    plan = compile(g, ["triad_census"], config)
+    return g, plan, plan.run(g)["triad_census"].counts
+
+
+@pytest.fixture(scope="module")
+def traced(tmp_path_factory):
+    """Every ``repro.*`` host event of one warm census:
+    ``[(name, start, end, stats, line)]``."""
+    from jax.profiler import ProfileData
+    arcs = _arcs()
+    _census(arcs)                    # compile outside the trace
+    out = str(tmp_path_factory.mktemp("trace"))
+    with jax.profiler.trace(out):
+        _census(arcs)
+    path, = glob.glob(f"{out}/**/*.xplane.pb", recursive=True)
+    events = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for i, line in enumerate(plane.lines):
+            events += [(e.name, e.start_ns, e.start_ns + e.duration_ns,
+                        dict(e.stats), (plane.name, i))
+                       for e in line.events if e.name.startswith("repro.")]
+    return events
+
+
+def _parent(event, events):
+    """The innermost other repro.* span on the same thread holding
+    ``event``, or None."""
+    name, s, e, _, line = event
+    holders = [h for h in events if h is not event and h[4] == line
+               and h[1] <= s and e <= h[2]]
+    return max(holders, key=lambda h: h[1])[0] if holders else None
+
+
+def test_every_span_opens_nested_as_documented(traced):
+    names = {e[0] for e in traced}
+    assert names == set(NESTING)
+    for event in traced:
+        assert _parent(event, traced) == NESTING[event[0]], event
+    runs = [e for e in traced if e[0] == "repro.run"]
+    assert len(runs) == 1 and runs[0][3]["run"] >= 1
+    compiles = [e for e in traced if e[0] == "repro.compile"]
+    assert [c[3]["hit"] for c in compiles] == [1]
+
+
+def test_chunk_spans_carry_their_bucket_and_bounds(traced):
+    chunks = [e[3] for e in traced if e[0] == "repro.chunk"]
+    assert len(chunks) > CONFIG.pipeline_depth
+    assert all({"K", "start", "end"} <= set(c) for c in chunks)
+    assert {c["K"] for c in chunks} <= {4, 8, 16, 32, 64}
+    assert all(c["start"] < c["end"] for c in chunks)
+
+
+def test_counters_agree_with_the_plans_own_schedule():
+    arcs = _arcs()
+    _, plan, _ = _census(arcs)
+    before = dict(plan.stats)
+    g, _, _ = _census(arcs)          # a fresh graph: the memo misses
+    tasks, = [ts for ref, ts in plan._task_memo.values() if ref() is g]
+    chunk = max(CONFIG.resolve_block(),
+                plan.chunk // CONFIG.resolve_block()
+                * CONFIG.resolve_block())
+    delta = {k: plan.stats[k] - before[k]
+             for k in ("tile_slots", "dyads", "bytes_staged",
+                       "task_memo_hits", "task_memo_misses")}
+    assert delta["tile_slots"] == sum(6 * chunk * t.key for t in tasks)
+    assert delta["dyads"] == sum(min(t.end, t.start + chunk) - t.start
+                                 for t in tasks) == g.n_dyads
+    assert delta["bytes_staged"] == sum(
+        a.nbytes for a in plan.padded_arrays_host(g) if a is not None)
+    assert (delta["task_memo_hits"], delta["task_memo_misses"]) == (0, 1)
+    plan.run(g)                      # the same graph again: a memo hit
+    assert plan.stats["task_memo_hits"] - before["task_memo_hits"] == 1
+
+
+def test_a_warm_pallas_census_adds_no_trace():
+    arcs = _arcs()
+    fresh = dataclasses.replace(CONFIG, buckets=(2, 8))  # a plan of its own
+    _, plan, first = _census(arcs, fresh)
+    assert plan.backend == "pallas" and plan.stats["traces"] > 0
+    traces = plan.stats["traces"]
+    _, again, second = _census(arcs, fresh)
+    assert again is plan and plan.stats["traces"] == traces
+    assert np.array_equal(first, second)

@@ -76,7 +76,7 @@ def test_fused_pallas_chunk_unit_fits_v5e(one_chip):
     hi/lo fold) at eatSR's top bucket: K = 8192, chunk = 8192."""
     config = EngineConfig(backend="pallas")
     layout = OpLayout(resolve_ops(("triad_census",)), EATSR_META, config)
-    fn = make_pallas_chunk_fn(layout, config)
+    fn = make_pallas_chunk_fn(layout, config, {"traces": 0})
     m = EATSR_META
     i32 = jnp.int32
     arrays = GraphArrays(

@@ -685,8 +685,14 @@ def run_pallas(plan, g: CSRGraph) -> np.ndarray:
 def count_tiles(stats: dict, tasks, chunk: int) -> None:
     """Count a census pass's tile work in ``stats``: ``tile_slots``, the
     slots of the six ``(chunk, K)`` tiles each task gathers (padding
-    included), and ``dyads``, the live dyads those tiles hold."""
+    included), ``gather_blocks``, the aligned blocks fetched to fill them
+    (the gather's indices, :func:`repro.kernels.ops.gather_blocks_per_row`
+    per tile row), and ``dyads``, the live dyads those tiles hold."""
+    from ..kernels.ops import gather_blocks_per_row
+
     stats["tile_slots"] += sum(6 * chunk * t.key for t in tasks)
+    stats["gather_blocks"] += sum(6 * chunk * gather_blocks_per_row(t.key)
+                                  for t in tasks)
     stats["dyads"] += sum(min(t.end, t.start + chunk) - t.start
                           for t in tasks)
 

@@ -165,7 +165,8 @@ class Plan:
         self.stats = {"traces": 0, "runs": 0, "chunks": 0, "host_syncs": 0,
                       "batch_runs": 0, "batch_graphs": 0, "device_chunks": {},
                       "delta_runs": 0, "delta_fulls": 0, "reorders": 0,
-                      "tile_slots": 0, "dyads": 0, "bytes_staged": 0,
+                      "tile_slots": 0, "gather_blocks": 0, "dyads": 0,
+                      "bytes_staged": 0,
                       "task_memo_hits": 0, "task_memo_misses": 0,
                       "faults": dict(chunk_failures=0, retries=0,
                                      device_losses=0, quarantines=0,

@@ -91,16 +91,46 @@ def build_in_csr_device(out_ptr: jax.Array, out_idx: jax.Array):
     return in_ptr, in_idx
 
 
+#: lanes of one gathered block: a CSR index array is fetched as aligned
+#: rows of this many int32s, not one element at a time
+LANES = 128
+
+
+def gather_blocks_per_row(K: int) -> int:
+    """Aligned ``LANES``-wide blocks fetched per tile row of width ``K``:
+    enough to cover ``K`` entries from any offset inside the first one."""
+    return -(-K // LANES) + 1
+
+
 def _gather_rows(ptr, idx, rows, row_valid, K: int):
-    """(B, K) SENTINEL-padded tile of CSR rows — the device ``_pad_rows``."""
+    """(B, K) SENTINEL-padded tile of CSR rows — the device ``_pad_rows``.
+
+    The gather's cost is per index, so ``idx`` is viewed as rows of
+    ``LANES`` lanes and each tile row fetches the
+    :func:`gather_blocks_per_row` aligned blocks from the one holding its
+    start: one gather index per block instead of one per entry.  A barrel
+    shift (one static lane shift per bit of ``start % LANES``, each
+    selected per row) then moves every row's first entry to lane 0.
+    """
     r = jnp.where(row_valid, rows, 0)
     start = ptr[r]
     deg = ptr[r + 1] - start
+    nb = gather_blocks_per_row(K)
+    # tail padding keeps every block index in range: the last start lies
+    # at most at len(idx), and nb - 1 blocks follow its own
+    n_blocks = -(-idx.shape[0] // LANES) + nb
+    blocks = jnp.pad(idx, (0, n_blocks * LANES - idx.shape[0]),
+                     constant_values=SENTINEL).reshape(n_blocks, LANES)
+    first = start // LANES
+    w = blocks[first[:, None] + jnp.arange(nb, dtype=jnp.int32)[None, :]]
+    w = w.reshape(-1, nb * LANES)
+    offset = start % LANES
+    for bit in range(LANES.bit_length() - 1):
+        s = 1 << bit
+        w = jnp.where((offset & s)[:, None] != 0, w[:, s:], w[:, :-s])
     j = jnp.arange(K, dtype=jnp.int32)
-    pos = jnp.clip(start[:, None] + j[None, :], 0, idx.shape[0] - 1)
-    w = idx[pos]
     live = row_valid[:, None] & (j[None, :] < deg[:, None])
-    return jnp.where(live, w, SENTINEL)
+    return jnp.where(live, w[:, :K], SENTINEL)
 
 
 @functools.partial(jax.jit, static_argnames=("K",))

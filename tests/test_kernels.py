@@ -77,3 +77,59 @@ def test_flash_attention_matches_model_chunked_path():
     xla = _chunked_attention(q, k, v, qp, qp, None, 64, triangular=True)
     pls = ops.flash_attention(q, k, v, qp, qp, chunk=64)
     assert float(jnp.abs(xla - pls).max()) < 2e-5
+
+
+def _gather_test_graph():
+    """Rows of out-degree exactly 4096, 512, 200, 128, 32 and 1
+    (vertices 0-5), degree-0 rows (6-9), a random sprinkle that spreads
+    row starts over every lane offset, and a last vertex with a few arcs
+    each way, so that its rows start in the last 128-lane block."""
+    from repro.core.graph import from_edges
+    n = 4200
+    src, dst = [], []
+    for h, k in enumerate((4096, 512, 200, 128, 32, 1)):
+        src += [h] * k
+        dst += range(10, 10 + k)
+    rng = np.random.default_rng(5)
+    src += list(rng.integers(10, n, 3000))
+    dst += list(rng.integers(10, n, 3000))
+    src += [n - 1, n - 1, n - 1, n - 2, n - 3]
+    dst += [20, 30, 40, n - 1, n - 1]
+    return from_edges(n, np.array(src), np.array(dst))
+
+
+@pytest.mark.parametrize("K", [1, 32, 128, 200, 512, 4096])
+def test_device_tile_gather_matches_host_tiles(K):
+    """gather_tiles_device == build_tiles bit for bit, and rows with
+    valid == False come back all-SENTINEL."""
+    g = _gather_test_graph()
+    n = g.n
+    rng = np.random.default_rng(K)
+    rows = np.concatenate([np.arange(10), np.arange(n - 20, n),
+                           rng.integers(10, n, 64)])
+    u = rows.astype(np.int64)
+    v = rng.permutation(rows).astype(np.int64)
+    valid = np.arange(len(rows)) % 7 != 6
+    in_ptr, in_idx = ops.build_in_csr_device(g.arrays.out_ptr,
+                                             g.arrays.out_idx)
+    arrays = g.arrays._replace(in_ptr=in_ptr, in_idx=in_idx)
+    # the rows cover what the block gather must get right
+    for ptr, idx in ((g.arrays.out_ptr, g.arrays.out_idx),
+                     (in_ptr, in_idx), (g.arrays.nbr_ptr, g.arrays.nbr_idx)):
+        ptr = np.asarray(ptr)
+        start, deg = ptr[rows], ptr[rows + 1] - ptr[rows]
+        last = (len(idx) - 1) // ops.LANES * ops.LANES
+        assert ((start >= last) & (deg > 0) & valid).any()
+        assert ((deg == 0) & valid).any()
+        assert len(set(start[valid] % ops.LANES)) > 16
+    assert (np.diff(np.asarray(g.arrays.out_ptr))[rows[valid]] == K).any()
+
+    got = ops.gather_tiles_device(arrays, jnp.asarray(u, jnp.int32),
+                                  jnp.asarray(v, jnp.int32),
+                                  jnp.asarray(valid), K=K)
+    want = ops.build_tiles(g, u, v, K)
+    for name, tile in want.items():
+        tile_got = np.asarray(got[name])
+        assert tile_got.shape == tile.shape == (len(rows), K)
+        assert (tile_got[valid] == tile[valid]).all(), name
+        assert (tile_got[~valid] == SENTINEL).all(), name

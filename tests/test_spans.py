@@ -87,6 +87,11 @@ def test_chunk_spans_carry_their_bucket_and_bounds(traced):
     assert all(c["start"] < c["end"] for c in chunks)
 
 
+def _blocks(tasks, chunk: int) -> int:
+    """Aligned 128-lane blocks the six tiles of every task fetch."""
+    return sum(6 * chunk * (-(-t.key // 128) + 1) for t in tasks)
+
+
 def test_counters_agree_with_the_plans_own_schedule():
     arcs = _arcs()
     _, plan, _ = _census(arcs)
@@ -97,9 +102,10 @@ def test_counters_agree_with_the_plans_own_schedule():
                 plan.chunk // CONFIG.resolve_block()
                 * CONFIG.resolve_block())
     delta = {k: plan.stats[k] - before[k]
-             for k in ("tile_slots", "dyads", "bytes_staged",
-                       "task_memo_hits", "task_memo_misses")}
+             for k in ("tile_slots", "gather_blocks", "dyads",
+                       "bytes_staged", "task_memo_hits", "task_memo_misses")}
     assert delta["tile_slots"] == sum(6 * chunk * t.key for t in tasks)
+    assert delta["gather_blocks"] == _blocks(tasks, chunk)
     assert delta["dyads"] == sum(min(t.end, t.start + chunk) - t.start
                                  for t in tasks) == g.n_dyads
     assert delta["bytes_staged"] == sum(
@@ -118,3 +124,30 @@ def test_a_warm_pallas_census_adds_no_trace():
     _, again, second = _census(arcs, fresh)
     assert again is plan and plan.stats["traces"] == traces
     assert np.array_equal(first, second)
+
+
+def test_gather_blocks_count_a_delta_pass(monkeypatch):
+    """A delta census (two subset passes) counts the blocks of the tasks
+    it dispatches, as the full pass does."""
+    from repro.core import GraphDelta
+    config = dataclasses.replace(CONFIG, delta_threshold=1.0)
+    arcs = _arcs()
+    g, plan, _ = _census(arcs, config)
+    raw = plan.run_raw(g)
+    chunk = max(config.resolve_block(),
+                plan.chunk // config.resolve_block()
+                * config.resolve_block())
+    dispatched = []
+    run = plan.executor.run
+
+    def spy(tasks, **kw):
+        dispatched.extend(tasks)
+        return run(tasks, **kw)
+
+    monkeypatch.setattr(plan.executor, "run", spy)
+    before = plan.stats["gather_blocks"]
+    delta = GraphDelta(edges_added=[(0, 9), (3, 17)],
+                       edges_removed=[(int(arcs[1][0]), int(arcs[2][0]))])
+    res = plan.apply_delta(g, delta, raw)
+    assert res.mode == "delta" and dispatched
+    assert plan.stats["gather_blocks"] - before == _blocks(dispatched, chunk)

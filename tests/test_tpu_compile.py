@@ -5,7 +5,9 @@ v5e that is described, not attached, and refuses what the chip would
 refuse (misaligned blocks, unsupported lowerings, VMEM overruns,
 programs larger than device memory).  Each compile takes a second or two.
 """
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -17,6 +19,7 @@ from repro.engine import EngineConfig
 from repro.engine.backends import make_pallas_chunk_fn
 from repro.engine.ops import OpLayout, resolve_ops
 from repro.engine.plan import GraphMeta
+from repro.kernels.ops import gather_blocks_per_row, gather_tiles_device
 from repro.kernels.triad_census import census_tiles_pallas
 
 #: full-size eatSR (``paper_profile("eatSR", scale_down=1)``) plan shapes
@@ -69,6 +72,42 @@ def test_census_kernel_compiles_for_v5e(one_chip, K):
         u, v, n, *t, block=32, interpret=False, reduce=False))
     compiled = fn.lower(vec, vec, n, *[tile] * 6).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _gather_index_counts(hlo: str) -> list:
+    """Indices of every gather in compiled HLO text: the result's
+    elements outside its offset (slice) dimensions."""
+    counts = []
+    for m in re.finditer(r"= \w+\[([\d,]*)\]\S* gather\(.*?"
+                         r"offset_dims=\{([\d,]*)\}", hlo):
+        dims = [int(d) for d in m.group(1).split(",") if d]
+        offset = {int(d) for d in m.group(2).split(",") if d}
+        counts.append(math.prod(d for i, d in enumerate(dims)
+                                if i not in offset))
+    return counts
+
+
+def test_tile_gather_fetches_blocks_for_v5e(one_chip):
+    """The top bucket's tile gather (chunk 8192, K = 4096) compiles to
+    block gathers with no loop: no gather takes more indices than one per
+    aligned block of every tile row."""
+    chunk, K = 8192, 4096
+    m = EATSR_META
+    i32 = jnp.int32
+    ptr = _spec((m.n_bucket + 1,), i32, one_chip)
+    out_idx = _spec((m.m_out_bucket,), i32, one_chip)
+    arrays = GraphArrays(
+        out_ptr=ptr, out_idx=out_idx, nbr_ptr=ptr,
+        nbr_idx=_spec((m.m_nbr_bucket,), i32, one_chip),
+        nbr_deg=_spec((m.n_bucket,), i32, one_chip),
+        in_ptr=ptr, in_idx=out_idx)
+    rows = _spec((chunk,), i32, one_chip)
+    valid = _spec((chunk,), jnp.bool_, one_chip)
+    hlo = gather_tiles_device.lower(arrays, rows, rows, valid,
+                                    K=K).compile().as_text()
+    assert " while(" not in hlo
+    counts = _gather_index_counts(hlo)
+    assert counts and max(counts) <= chunk * gather_blocks_per_row(K), counts
 
 
 def test_fused_pallas_chunk_unit_fits_v5e(one_chip):

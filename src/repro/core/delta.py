@@ -17,8 +17,9 @@ undirected CSR rows of the touched vertices.
 This module is pure host/NumPy: :class:`GraphDelta` (validated, deduped
 edge lists), :func:`affected_dyads` (the exact canonical-dyad blast
 radius on one graph), and :func:`apply_delta_csr` (the mutated
-:class:`~repro.core.graph.CSRGraph`).  The device-side correction pass
-lives in :mod:`repro.engine.delta`.
+:class:`~repro.core.graph.CSRGraph`), each call inside a span
+(``repro.affected``, ``repro.apply_csr``).  The device-side correction
+pass lives in :mod:`repro.engine.delta`.
 """
 from __future__ import annotations
 
@@ -27,6 +28,7 @@ import dataclasses
 import numpy as np
 
 from .graph import CSRGraph, arcs_host, from_edges
+from .spans import spanned
 
 __all__ = ["GraphDelta", "affected_dyads", "apply_delta_csr"]
 
@@ -125,6 +127,7 @@ class GraphDelta:
                 "from_edges to grow it)")
 
 
+@spanned("affected")
 def affected_dyads(g: CSRGraph, delta: GraphDelta
                    ) -> "tuple[np.ndarray, np.ndarray]":
     """Canonical dyads of ``g`` whose kernel contribution the delta can
@@ -162,6 +165,7 @@ def affected_dyads(g: CSRGraph, delta: GraphDelta
     return ((key // g.n).astype(np.int32), (key % g.n).astype(np.int32))
 
 
+@spanned("apply_csr")
 def apply_delta_csr(g: CSRGraph, delta: GraphDelta) -> CSRGraph:
     """The mutated graph: ``g``'s arcs minus ``edges_removed`` plus
     ``edges_added``, rebuilt through the same

@@ -45,6 +45,7 @@ import numpy as np
 from ..core import balance
 from ..core.delta import GraphDelta, affected_dyads, apply_delta_csr
 from ..core.graph import CSRGraph
+from ..core.spans import span, spanned
 from .executor import _ACC_SHIFT, ChunkTask, _acc_fetch
 from .faults import InjectedFault, resolve_faults
 
@@ -218,6 +219,7 @@ def _subset_distributed(plan, g: CSRGraph, u: np.ndarray, v: np.ndarray, *,
     return plan.executor.run(tasks, place=place, step=step, init=init)
 
 
+@spanned("delta_schedule")
 def _pallas_subset_schedule(plan, g: CSRGraph, u: np.ndarray, v: np.ndarray):
     """Host-side (bucket, need) schedule for a pallas pass over the dyad
     sublist ``(u, v)`` — the subset mirror of the full pass's device sort,
@@ -228,7 +230,8 @@ def _pallas_subset_schedule(plan, g: CSRGraph, u: np.ndarray, v: np.ndarray):
     Returns ``(u, v, tasks, chunk, block, interpret)`` with ``u``/``v``
     REORDERED into bucket-sorted order: every :class:`ChunkTask` carries
     the ``K`` specialization its span compiles against, so each dispatch
-    hits an already-compiled tile kernel."""
+    hits an already-compiled tile kernel.  Runs inside the span
+    ``repro.delta_schedule``."""
     cfg = plan.config
     interpret = cfg.resolve_interpret()
     block = cfg.resolve_block()
@@ -328,7 +331,10 @@ def delta_correction(plan, g_old: CSRGraph, g_new: CSRGraph,
 
     ``affected_old`` / ``affected_new`` accept precomputed
     :func:`~repro.core.delta.affected_dyads` pairs so the caller's
-    footprint measurement isn't recomputed."""
+    footprint measurement isn't recomputed.  Adds both passes' affected
+    dyads to ``stats["delta_affected"]`` and the chunks they dispatch to
+    ``stats["delta_chunks"]``; the difference and its fetch run inside
+    the span ``repro.delta_fold``."""
     ou, ov = (affected_dyads(g_old, delta) if affected_old is None
               else affected_old)
     nu, nv = (affected_dyads(g_new, delta) if affected_new is None
@@ -341,10 +347,14 @@ def delta_correction(plan, g_old: CSRGraph, g_new: CSRGraph,
         from .partition import subset_partitioned as runner
     else:
         runner = _SUBSET_RUNNERS[plan.backend]
+    chunks = plan.stats["chunks"]
     hi_o, lo_o = runner(plan, g_old, ou, ov)
     hi_n, lo_n = runner(plan, g_new, nu, nv)
-    hi, lo = _acc_diff(hi_n, lo_n, hi_o, lo_o)
-    return _acc_fetch(plan, hi, lo)
+    plan.stats["delta_chunks"] += plan.stats["chunks"] - chunks
+    plan.stats["delta_affected"] += len(ou) + len(nu)
+    with span("delta_fold"):
+        hi, lo = _acc_diff(hi_n, lo_n, hi_o, lo_o)
+        return _acc_fetch(plan, hi, lo)
 
 
 def run_delta(plan, g: CSRGraph, delta: GraphDelta,
@@ -365,7 +375,15 @@ def run_delta(plan, g: CSRGraph, delta: GraphDelta,
     the relabeled new graph IS the relabeling of the new graph (seeded
     into the reorder memo: a mutation stream reuses one permutation and
     every step stays warm).  The correction maps back through the inverse
-    permutation before folding — exact, because ``unpermute`` is linear."""
+    permutation before folding — exact, because ``unpermute`` is linear.
+
+    Runs inside the span ``repro.delta``, whose args ``affected_old`` /
+    ``affected_new`` are the two affected-dyad counts."""
+    with span("delta") as sp:
+        return _run_delta(plan, g, delta, raw, sp)
+
+
+def _run_delta(plan, g: CSRGraph, delta: GraphDelta, raw, sp) -> DeltaResult:
     g_new = apply_delta_csr(g, delta)
     plan._check(g_new)
     fplan = resolve_faults(plan.config.fault_plan)
@@ -405,6 +423,8 @@ def run_delta(plan, g: CSRGraph, delta: GraphDelta,
         delta_x, g_new_x = delta, g_new
     affected_old = affected_dyads(g_x, delta_x)
     affected_new = affected_dyads(g_new_x, delta_x)
+    sp.set_metadata(affected_old=len(affected_old[0]),
+                    affected_new=len(affected_new[0]))
     frac = affected_fraction(g_x, g_new_x, len(affected_old[0]),
                              len(affected_new[0]))
     use_delta = (raw is not None and plan.device_path

@@ -164,7 +164,8 @@ class Plan:
                     "'serial'")
         self.stats = {"traces": 0, "runs": 0, "chunks": 0, "host_syncs": 0,
                       "batch_runs": 0, "batch_graphs": 0, "device_chunks": {},
-                      "delta_runs": 0, "delta_fulls": 0, "reorders": 0,
+                      "delta_runs": 0, "delta_fulls": 0,
+                      "delta_affected": 0, "delta_chunks": 0, "reorders": 0,
                       "tile_slots": 0, "gather_blocks": 0, "dyads": 0,
                       "bytes_staged": 0,
                       "task_memo_hits": 0, "task_memo_misses": 0,
@@ -773,7 +774,9 @@ def plan_cache_stats() -> dict:
     ``n_devices`` — the resolved pool width), and the plan's live
     execution counters (``runs``, ``batch_runs``, ``batch_graphs``,
     ``traces``, ``chunks``, ``host_syncs``, ``delta_runs`` /
-    ``delta_fulls`` — incremental applications split by path — plus
+    ``delta_fulls`` — incremental applications split by path — and
+    ``delta_affected`` / ``delta_chunks`` — affected dyads over both
+    subset passes and the chunks those passes dispatched — plus
     ``faults`` / ``fault_events``: the executor's recovery counters and
     bounded event trace (retries, quarantines, device losses,
     fallbacks), ``device_chunks``: chunks dispatched per executor pool

@@ -58,6 +58,7 @@ from typing import Any, Dict, Iterable, List, NamedTuple, Optional, Tuple
 
 from ..core.delta import GraphDelta, apply_delta_csr
 from ..core.graph import CSRGraph
+from ..core.spans import span
 from ..engine import CensusConfig, GraphMeta, PlanShapeError, compile
 from ..engine.ops import get_op, resolve_ops
 
@@ -445,7 +446,15 @@ class CensusService:
         snapshot, so a subscribed session never serves corrupted counts
         — :meth:`poll`\\ (session) keeps answering from the last good
         state.  Rolled-back mutations are counted per session
-        (``failed``) and in ``stats()["health"]["mutate_failures"]``."""
+        (``failed``) and in ``stats()["health"]["mutate_failures"]``.
+
+        Runs inside the span ``repro.mutate`` (arg ``mode``)."""
+        with span("mutate") as sp:
+            ack = self._mutate(session, delta)
+            sp.set_metadata(mode=ack["mode"])
+        return ack
+
+    def _mutate(self, session: int, delta: GraphDelta) -> dict:
         s = self._session(session)
         snapshot = (s.graph, s.raw, s.plan)
         try:

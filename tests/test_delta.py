@@ -24,6 +24,7 @@ from repro.serve import CensusService, ServiceConfig
 BACKENDS = ["xla", "pallas", "distributed"]
 ALL_OPS = ("triad_census", "dyad_census", "degree_stats", "triadic_profile")
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
+BENCH = os.path.join(os.path.dirname(__file__), "..", "bench")
 
 
 @pytest.fixture(autouse=True)
@@ -246,13 +247,20 @@ def test_property_random_mutations_hypothesis():
                               st.lists(edge, max_size=4)),
                     min_size=1, max_size=4))
     def prop(seq):
-        cur, raw = g0, base_raw
+        p, cur, raw = plan, g0, base_raw
         for add, rem in seq:
-            res = plan.apply_delta(
-                cur, GraphDelta(edges_added=add or None,
-                                edges_removed=rem or None), raw)
-            cur, raw = res.graph, res.raw
-        assert np.array_equal(raw, plan.run_raw(cur))
+            d = GraphDelta(edges_added=add or None,
+                           edges_removed=rem or None)
+            try:
+                res = p.apply_delta(cur, d, raw)
+                cur, raw = res.graph, res.raw
+            except PlanShapeError:
+                # the arcs outgrew the plan's buckets: recompile at the
+                # new shape and reseed, as CensusService.mutate does
+                cur = apply_delta_csr(cur, d)
+                p = compile(cur, ALL_OPS, _cfg("xla"))
+                raw = p.run_raw(cur)
+        assert np.array_equal(raw, p.run_raw(cur))
 
     prop()
 
@@ -392,6 +400,67 @@ def test_session_limit_and_stateless_poll_coexist():
     done = svc.flush()
     assert [c.request_id for c in done] == [rid]
     assert svc.poll() == []  # no-arg poll keeps its drain semantics
+
+
+def _benchlib():
+    """The benchmark's generator, plain reference and mutation stream:
+    host code that shares nothing with the program."""
+    if BENCH not in sys.path:
+        sys.path.insert(0, BENCH)
+    from benchlib import generators as bench_generators
+    from benchlib import reference
+    from benchlib.mutations import Stream
+    return bench_generators, reference, Stream
+
+
+@pytest.mark.parametrize("backend", ["xla", "pallas"])
+def test_session_follows_the_held_back_stream_exactly(backend):
+    """The benchmark's session on a small Kronecker graph: 12 batches of
+    two arcs in and two out, drawn from a held-back pool; every poll
+    equals the plain reference of the arc list the stream tracked."""
+    bench_generators, reference, Stream = _benchlib()
+    n, src, dst = bench_generators.kronecker(6, 8, seed=0)
+    stream = Stream(n, src, dst, 32, np.random.default_rng(3))
+    svc = CensusService(ServiceConfig(census=_cfg(backend)))
+    sid = svc.subscribe(from_edges(*stream.arcs()))
+    modes = []
+    for _ in range(12):
+        added, removed = stream.swap(*stream.draw(2, 2))
+        ack = svc.mutate(sid, GraphDelta(edges_added=added,
+                                         edges_removed=removed))
+        modes.append(ack["mode"])
+        assert ack["m"] == len(src) - 32
+        assert np.array_equal(svc.poll(sid).counts,
+                              reference.triad_census(*stream.arcs()))
+    assert modes.count("delta") >= 10 and set(modes) <= {"delta",
+                                                         "recompile"}
+    assert svc._sessions[sid].plan.backend == backend
+
+
+@pytest.mark.parametrize("backend", ["xla", "pallas"])
+def test_delta_counters_match_the_affected_sets_and_the_schedule(backend):
+    from repro.engine.delta import _pallas_subset_schedule, _subset_tasks
+    g = generators.rmat(6, edge_factor=4, seed=3)
+    plan = compile(g, ("triad_census",), _cfg(backend))
+    raw = plan.run_raw(g)
+    d = _rand_delta(g, np.random.default_rng(5))
+    before = dict(plan.stats)
+    res = plan.apply_delta(g, d, raw)
+    assert res.mode == "delta" and plan.backend == backend
+    sets = [affected_dyads(g, d), affected_dyads(res.graph, d)]
+    if backend == "pallas":
+        tasks = [_pallas_subset_schedule(plan, x, u, v)[2]
+                 for x, (u, v) in zip((g, res.graph), sets)]
+    else:
+        tasks = [_subset_tasks(plan, x, u, v, plan.chunk)
+                 for x, (u, v) in zip((g, res.graph), sets)]
+    assert (plan.stats["delta_affected"] - before["delta_affected"]
+            == sum(len(u) for u, _ in sets) > 0)
+    assert (plan.stats["delta_chunks"] - before["delta_chunks"]
+            == sum(len(t) for t in tasks) > 0)
+    entry, = plan_cache_stats()["entries"]
+    assert entry["delta_affected"] == plan.stats["delta_affected"]
+    assert entry["delta_chunks"] == plan.stats["delta_chunks"]
 
 
 def test_session_recompile_on_bucket_outgrowth():

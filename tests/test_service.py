@@ -1,5 +1,8 @@
 """Batched serving layer: run_batch bit-identity, batching policy,
-out-of-order completion, per-bucket stats, cache-entry metadata."""
+out-of-order completion, per-bucket stats, cache-entry metadata, and
+the spans a session's mutations tally."""
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -241,3 +244,40 @@ def test_run_fleet_returns_input_order():
     assert len(out) == 5
     for res, g in zip(out, fleet):
         assert (res.counts == brute_force_census(g).counts).all()
+
+
+# ----------------------------------------------------------------------------
+# subscribed sessions: the spans' tally of what a mutation does
+# ----------------------------------------------------------------------------
+
+def _tally_window(before, after):
+    return {k: c - before.get(k, (0.0, 0))[1] for k, (_, c) in after.items()
+            if c != before.get(k, (0.0, 0))[1]}
+
+
+def test_session_mutations_tally_their_spans_by_mode():
+    from repro.core import GraphDelta
+    from repro.core.spans import span_totals
+    svc = CensusService(ServiceConfig(
+        census=dataclasses.replace(CFG, delta_threshold=1.0)))
+    g = from_edges(32, [0, 1, 2, 3], [1, 2, 3, 4])
+    sid = svc.subscribe(g)
+    before = span_totals()
+    ack = svc.mutate(sid, GraphDelta(edges_added=[(4, 5)],
+                                     edges_removed=[(0, 1)]))
+    assert ack["mode"] == "delta"
+    calls = _tally_window(before, span_totals())
+    assert {k: calls.get(k) for k in ("mutate", "delta", "apply_csr",
+                                      "affected", "delta_fold", "fetch")} \
+        == {"mutate": 1, "delta": 1, "apply_csr": 1, "affected": 2,
+            "delta_fold": 1, "fetch": 1}
+    # outgrowing the plan's buckets recompiles: the CSR is rebuilt, and
+    # no correction runs
+    before = span_totals()
+    hub = GraphDelta(edges_added=[(0, v) for v in range(5, 31)])
+    assert svc.mutate(sid, hub)["mode"] == "recompile"
+    calls = _tally_window(before, span_totals())
+    assert calls["mutate"] == 1 and "delta_fold" not in calls
+    assert calls["apply_csr"] >= 1
+    cur = svc._sessions[sid].graph
+    assert (svc.poll(sid).counts == brute_force_census(cur).counts).all()

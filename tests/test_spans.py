@@ -1,7 +1,9 @@
 """The engine's spans and counters: a small census on the pallas path
 (interpret mode on CPU) under ``jax.profiler.trace`` opens every
 ``repro.*`` span, nested as documented, and its counters agree with the
-plan's own chunk schedule."""
+plan's own chunk schedule; so does one mutation of a subscribed
+session.  The spans' tally (``span_totals``) counts nested spans and
+spans opened on other threads."""
 import dataclasses
 import glob
 
@@ -37,16 +39,10 @@ def _census(arcs, config=CONFIG):
     return g, plan, plan.run(g)["triad_census"].counts
 
 
-@pytest.fixture(scope="module")
-def traced(tmp_path_factory):
-    """Every ``repro.*`` host event of one warm census:
+def _host_events(out):
+    """Every ``repro.*`` host event of the trace written under ``out``:
     ``[(name, start, end, stats, line)]``."""
     from jax.profiler import ProfileData
-    arcs = _arcs()
-    _census(arcs)                    # compile outside the trace
-    out = str(tmp_path_factory.mktemp("trace"))
-    with jax.profiler.trace(out):
-        _census(arcs)
     path, = glob.glob(f"{out}/**/*.xplane.pb", recursive=True)
     events = []
     for plane in ProfileData.from_file(path).planes:
@@ -57,6 +53,17 @@ def traced(tmp_path_factory):
                         dict(e.stats), (plane.name, i))
                        for e in line.events if e.name.startswith("repro.")]
     return events
+
+
+@pytest.fixture(scope="module")
+def traced(tmp_path_factory):
+    """Every ``repro.*`` host event of one warm census."""
+    arcs = _arcs()
+    _census(arcs)                    # compile outside the trace
+    out = str(tmp_path_factory.mktemp("trace"))
+    with jax.profiler.trace(out):
+        _census(arcs)
+    return _host_events(out)
 
 
 def _parent(event, events):
@@ -151,3 +158,121 @@ def test_gather_blocks_count_a_delta_pass(monkeypatch):
     res = plan.apply_delta(g, delta, raw)
     assert res.mode == "delta" and dispatched
     assert plan.stats["gather_blocks"] - before == _blocks(dispatched, chunk)
+
+
+# span -> the span it opens inside, for one subscribed mutation on the
+# delta path
+DELTA_NESTING = {"repro.mutate": None, "repro.run": "repro.mutate",
+                 "repro.delta": "repro.run",
+                 "repro.apply_csr": "repro.delta",
+                 "repro.from_edges": "repro.apply_csr",
+                 "repro.affected": "repro.delta",
+                 "repro.stage": "repro.delta",
+                 "repro.delta_schedule": "repro.delta",
+                 "repro.chunk": "repro.delta", "repro.wait": "repro.delta",
+                 "repro.delta_fold": "repro.delta",
+                 "repro.fetch": "repro.delta_fold"}
+
+
+def test_a_mutation_opens_the_delta_spans_nested_as_documented(tmp_path):
+    from repro.core import GraphDelta, affected_dyads
+    from repro.serve import CensusService, ServiceConfig
+    n, src, dst = _arcs()
+    svc = CensusService(ServiceConfig(
+        census=dataclasses.replace(CONFIG, delta_threshold=1.0)))
+    sid = svc.subscribe(from_edges(n, src, dst))
+    warm = GraphDelta(edges_added=[(0, 9)],
+                      edges_removed=[(int(src[0]), int(dst[0]))])
+    svc.mutate(sid, warm)            # compile outside the trace
+    old = svc._sessions[sid].graph
+    delta = GraphDelta(edges_added=[(3, 17), (5, 40)],
+                       edges_removed=[(int(src[1]), int(dst[1]))])
+    with jax.profiler.trace(str(tmp_path)):
+        ack = svc.mutate(sid, delta)
+    events = _host_events(str(tmp_path))
+    names = {e[0] for e in events}
+    assert set(DELTA_NESTING) - {"repro.wait"} <= names <= set(DELTA_NESTING)
+    for event in events:
+        assert _parent(event, events) == DELTA_NESTING[event[0]], event
+    assert ack["mode"] == "delta"
+    mutate, = [e[3] for e in events if e[0] == "repro.mutate"]
+    assert mutate["mode"] == "delta"
+    stats, = [e[3] for e in events if e[0] == "repro.delta"]
+    new = svc._sessions[sid].graph
+    assert stats["affected_old"] == len(affected_dyads(old, delta)[0])
+    assert stats["affected_new"] == len(affected_dyads(new, delta)[0])
+    assert len([e for e in events if e[0] == "repro.affected"]) == 2
+
+
+def test_span_totals_count_nested_spans_and_threads():
+    """Nested spans count each under its name; spans closed on more
+    threads than cores, switching as often as the interpreter allows,
+    lose no call."""
+    import os
+    import sys
+    from concurrent.futures import ThreadPoolExecutor, wait
+
+    from repro.core.spans import span, span_totals
+    before = span_totals()
+    with span("tally_outer"):
+        for _ in range(2):
+            with span("tally_inner"):
+                pass
+
+    def opened(_):
+        for _ in range(50):
+            with span("tally_thread", i=1):
+                pass
+    workers = (os.cpu_count() or 1) + 2
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        with ThreadPoolExecutor(workers) as pool:
+            futures = [pool.submit(opened, i) for i in range(2 * workers)]
+            done, _ = wait(futures, timeout=120)
+            assert len(done) == len(futures)
+            for f in futures:
+                f.result()
+    finally:
+        sys.setswitchinterval(interval)
+    after = span_totals()
+
+    def window(name):
+        s0, c0 = before.get(name, (0.0, 0))
+        s1, c1 = after[name]
+        return s1 - s0, c1 - c0
+    assert [window(k)[1] for k in ("tally_outer", "tally_inner",
+                                   "tally_thread")] == [1, 2,
+                                                        100 * workers]
+    assert window("tally_outer")[0] >= window("tally_inner")[0] > 0
+
+
+def test_span_totals_count_every_chunk_of_a_census():
+    from repro.core.spans import span_totals
+    arcs = _arcs()
+    _, plan, _ = _census(arcs)
+    before, chunks = span_totals(), plan.stats["chunks"]
+    _census(arcs)
+    after = span_totals()
+    assert (after["chunk"][1] - before["chunk"][1]
+            == plan.stats["chunks"] - chunks > 0)
+    assert after["run"][1] - before["run"][1] == 1
+
+
+@pytest.mark.parametrize("fn_name,span_name", [
+    ("affected_dyads", "affected"), ("apply_delta_csr", "apply_csr")])
+def test_a_spanned_function_keeps_its_name_and_tallies_each_call(
+        fn_name, span_name):
+    from repro.core import GraphDelta
+    from repro.core import delta as core_delta
+    from repro.core.spans import span_totals
+    fn = getattr(core_delta, fn_name)
+    assert fn.__name__ == fn_name and fn.__doc__
+    n, src, dst = _arcs()
+    g = from_edges(n, src, dst)
+    d = GraphDelta(edges_added=[(0, 9)],
+                   edges_removed=[(int(src[0]), int(dst[0]))])
+    calls = span_totals().get(span_name, (0.0, 0))[1]
+    fn(g, d)
+    fn(g, d)
+    assert span_totals()[span_name][1] == calls + 2

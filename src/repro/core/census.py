@@ -227,13 +227,13 @@ def enumerate_dyads_device(nbr_ptr: jax.Array, nbr_idx: jax.Array,
 
 
 @functools.partial(jax.jit, static_argnames=("ks",))
-def sort_dyads_by_bucket(nbr_deg: jax.Array, out_ptr: jax.Array,
-                         u: jax.Array, v: jax.Array, n_dyads: jax.Array, *,
-                         ks: tuple):
+def sort_dyads_by_bucket(nbr_deg: jax.Array, u: jax.Array, v: jax.Array,
+                         n_dyads: jax.Array, *, ks: tuple):
     """Device-side degree-bucket assignment + load-balancing sort.
 
-    For each dyad the tile-width *need* is ``max(deg(u), deg(v),
-    out_deg(u), out_deg(v))``; its bucket is the smallest ``ks[i] >= need``.
+    For each dyad the tile-width *need* is ``max(deg(u), deg(v))``, the
+    longer of its two neighbour rows; its bucket is the smallest
+    ``ks[i] >= need``.
     Dyads are stable-sorted by (bucket, need) — two chained stable argsorts,
     which avoids composing a single wide sort key that could overflow int32
     — so tile rows inside a chunk are degree-ordered: gathers hit
@@ -244,9 +244,7 @@ def sort_dyads_by_bucket(nbr_deg: jax.Array, out_ptr: jax.Array,
     of static length ``len(ks)`` — the only value the host needs to drive
     the per-bucket chunk loop (one scalar-array transfer per run).
     """
-    out_deg = out_ptr[1:] - out_ptr[:-1]
-    need = jnp.maximum(jnp.maximum(nbr_deg[u], nbr_deg[v]),
-                       jnp.maximum(out_deg[u], out_deg[v]))
+    need = jnp.maximum(nbr_deg[u], nbr_deg[v])
     ks_arr = jnp.asarray(ks, dtype=jnp.int32)
     b = jnp.sum(need[:, None] > ks_arr[None, :], axis=1).astype(jnp.int32)
     live = jnp.arange(u.shape[0], dtype=jnp.int32) < n_dyads
@@ -258,7 +256,7 @@ def sort_dyads_by_bucket(nbr_deg: jax.Array, out_ptr: jax.Array,
 
 
 def host_bucket_schedule(g: CSRGraph, ks: tuple, *,
-                         with_needs: bool = True
+                         with_needs: bool = True, dyads=None
                          ) -> "tuple[np.ndarray, np.ndarray | None]":
     """Host-side mirror of :func:`sort_dyads_by_bucket`'s control outputs.
 
@@ -283,12 +281,12 @@ def host_bucket_schedule(g: CSRGraph, ks: tuple, *,
     multisets are permutation-invariant, so bucket counts match the
     unreordered run's exactly while the per-dyad sort order follows the
     relabeled stream the device actually executes.
+
+    ``dyads`` passes ``canonical_dyads(g)`` when the caller has them.
     """
-    u, v = canonical_dyads(g)
+    u, v = canonical_dyads(g) if dyads is None else dyads
     deg = np.asarray(g.arrays.nbr_deg)
-    out_deg = np.diff(np.asarray(g.arrays.out_ptr))
-    need = np.maximum(np.maximum(deg[u], deg[v]),
-                      np.maximum(out_deg[u], out_deg[v])).astype(np.int64)
+    need = np.maximum(deg[u], deg[v]).astype(np.int64)
     ks_arr = np.asarray(ks, dtype=np.int64)
     b = (need[:, None] > ks_arr[None, :]).sum(1)
     counts = np.bincount(b, minlength=len(ks))[: len(ks)].astype(np.int64)

@@ -13,6 +13,12 @@ Two CSRs are kept, mirroring the paper's implementation (Fig. 4.1):
   * ``nbr_ptr/nbr_idx``   — open undirected neighborhoods ``N(u)``
                             (union of in- and out-arcs), used for the
                             candidate set ``S`` and ``IsNeighbour``.
+
+The undirected rows also carry a direction-coded twin, ``nbr_code``:
+entry ``4·w + dir_u(w)`` beside each ``w`` of ``N(u)``, where bit 0 of
+``dir_u(w)`` says ``u -> w`` and bit 1 says ``w -> u``.  Since
+``N(u) = OUT(u) ∪ IN(u)`` exactly, one lookup in ``N(u)`` answers both
+directed probes of the pair, which is what the Pallas census kernel reads.
 """
 from __future__ import annotations
 
@@ -32,14 +38,19 @@ def next_pow2(x: int) -> int:
     return 1 << max(0, int(x) - 1).bit_length() if x > 1 else 1
 
 
+#: vertex count up to which ``nbr_code`` is exact: the largest code,
+#: ``4·(n-1) + 3``, must stay below the kernels' int32 ``SENTINEL`` 2**30.
+MAX_CODED_VERTICES = 1 << 28
+
+
 class GraphArrays(NamedTuple):
     """Device-resident graph (a JAX pytree; all int32).
 
-    ``in_ptr``/``in_idx`` hold the transpose (in-arc) CSR used by the Pallas
-    tile-gather path.  They default to ``None`` (an empty pytree subtree):
-    only plans that need them pay for the device-side transpose build — see
-    :func:`repro.kernels.ops.build_in_csr_device` and
-    ``CensusPlan.padded_arrays``.
+    ``nbr_code`` shares ``nbr_ptr`` with ``nbr_idx``: entry ``4·w + dir``
+    for each ``w`` of ``nbr_idx``, ``dir`` = 1 (arc ``u -> w`` only), 2
+    (``w -> u`` only) or 3 (mutual).  Exact while ``n <=
+    MAX_CODED_VERTICES``; the Pallas backend, its reader, refuses larger
+    graphs (``Plan`` demotes them to xla).
     """
 
     out_ptr: jax.Array  # (n+1,)
@@ -47,8 +58,7 @@ class GraphArrays(NamedTuple):
     nbr_ptr: jax.Array  # (n+1,)
     nbr_idx: jax.Array  # (m_nbr,) sorted within each row
     nbr_deg: jax.Array  # (n,) undirected open-neighborhood sizes
-    in_ptr: jax.Array | None = None  # (n+1,) transpose CSR (device-built)
-    in_idx: jax.Array | None = None  # (m,)
+    nbr_code: jax.Array  # (m_nbr,) 4·nbr_idx + direction bits
 
 
 @dataclasses.dataclass(frozen=True)
@@ -97,20 +107,28 @@ def _build_host_arrays(n: int, src, dst, *, directed: bool = True):
         src, dst = src[uniq], dst[uniq]
     out_ptr, out_idx = _build_csr(n, src, dst)
 
-    # undirected open neighborhoods: union of arcs in both directions
-    if src.size:
-        usrc = np.concatenate([src, dst])
-        udst = np.concatenate([dst, src])
-        ukey = usrc * np.int64(n) + udst
-        _, uniq = np.unique(ukey, return_index=True)
-        usrc, udst = usrc[uniq], udst[uniq]
-    else:
-        usrc, udst = src, dst
-    nbr_ptr, nbr_idx = _build_csr(n, usrc, udst)
+    # undirected open neighborhoods, each entry with its direction bits:
+    # every arc seen from its source (bit 0, out) and from its target
+    # (bit 1, in).  The arcs are sorted by (src, dst) now, so the two runs
+    # of keys are sorted and one stable sort merges them; a mutual pair
+    # gives two equal keys, whose bits add.
+    fwd = src * np.int64(n) + dst
+    keys = np.concatenate([fwd, np.sort(dst * np.int64(n) + src)])
+    order = np.argsort(keys, kind="stable")
+    keys = keys[order]
+    first = np.ones(keys.size, bool)
+    first[1:] = keys[1:] != keys[:-1]
+    direction = np.bincount(np.cumsum(first) - 1,
+                            weights=np.where(order < fwd.size, 1, 2))
+    usrc, udst = np.divmod(keys[first], np.int64(n))
+    nbr_ptr = np.zeros(n + 1, np.int64)
+    nbr_ptr[1:] = np.cumsum(np.bincount(usrc, minlength=n))
+    nbr_ptr, nbr_idx = nbr_ptr.astype(np.int32), udst.astype(np.int32)
+    nbr_code = (4 * udst + direction.astype(np.int64)).astype(np.int32)
     deg = (nbr_ptr[1:] - nbr_ptr[:-1]).astype(np.int32)
     out_deg = out_ptr[1:] - out_ptr[:-1]
     arrays = GraphArrays(out_ptr=out_ptr, out_idx=out_idx, nbr_ptr=nbr_ptr,
-                         nbr_idx=nbr_idx, nbr_deg=deg)
+                         nbr_idx=nbr_idx, nbr_deg=deg, nbr_code=nbr_code)
     return (arrays, int(src.size), int(usrc.size),
             int(deg.max()) if n and deg.size else 0,
             int(out_deg.max()) if n and out_deg.size else 0)
@@ -126,13 +144,7 @@ def from_edges(n: int, src, dst, *, directed: bool = True) -> CSRGraph:
     with span("from_edges"):
         host, m, m_nbr, max_deg, max_out_deg = _build_host_arrays(
             n, src, dst, directed=directed)
-        arrays = GraphArrays(
-            out_ptr=jnp.asarray(host.out_ptr),
-            out_idx=jnp.asarray(host.out_idx),
-            nbr_ptr=jnp.asarray(host.nbr_ptr),
-            nbr_idx=jnp.asarray(host.nbr_idx),
-            nbr_deg=jnp.asarray(host.nbr_deg),
-        )
+        arrays = GraphArrays(*(jnp.asarray(a) for a in host))
     return CSRGraph(n=n, m=m, m_nbr=m_nbr, max_deg=max_deg,
                     max_out_deg=max_out_deg, arrays=arrays)
 
@@ -174,8 +186,7 @@ def from_edges_mmap(n: int, src, dst, *, directed: bool = True,
         return np.load(path, mmap_mode="r")
 
     arrays = GraphArrays(**{f: spill(f, v) for f, v in
-                            zip(("out_ptr", "out_idx", "nbr_ptr", "nbr_idx",
-                                 "nbr_deg"), host[:5])})
+                            zip(GraphArrays._fields, host)})
     return CSRGraph(n=n, m=m, m_nbr=m_nbr, max_deg=max_deg,
                     max_out_deg=max_out_deg, arrays=arrays)
 
@@ -227,15 +238,12 @@ def stack_graph_arrays(arrays: "list[GraphArrays]") -> GraphArrays:
     must already share identical (bucket-padded) shapes — i.e. come from
     one plan's ``padded_arrays``/``padded_arrays_host`` — which is exactly
     the same-bucket admission rule ``CensusPlan.run_batch`` enforces.
-    Optional fields (the transpose CSR) stay ``None`` unless present on
-    every member.  Host (numpy) members are stacked on host and shipped
-    as ONE device put per field — the cheap path for fleet batching;
-    device members are stacked with ``jnp.stack``.
+    Host (numpy) members are stacked on host and shipped as ONE device
+    put per field — the cheap path for fleet batching; device members are
+    stacked with ``jnp.stack``.
     """
     def stk(field):
         vals = [getattr(a, field) for a in arrays]
-        if any(v is None for v in vals):
-            return None
         if all(isinstance(v, np.ndarray) for v in vals):
             return jnp.asarray(np.stack(vals))
         return jnp.stack(vals)

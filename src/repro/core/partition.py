@@ -24,10 +24,9 @@ chunk kernel's contribution for a dyad ``(u, v)`` reads only rows of
 is a partner, and every probed third vertex ``w`` is a neighbor of one of
 them; keeping those rows IN FULL (never truncated) means membership
 probes see exactly the global CSR row and results are bit-identical to
-the unpartitioned pass.  The in-arc tiles the pallas census path gathers
-are covered too: an in-arc ``w -> u`` implies ``w ∈ N(u)``, so ``w``'s
-full out-row is local and the shard-local transpose CSR is complete for
-every kept row.
+the unpartitioned pass.  The pallas census path reads the
+direction-coded twin of the same rows (``nbr_code``), kept in full
+beside ``nbr_idx``.
 
 Everything here is plain numpy over host views of the graph arrays —
 memory-mapped graphs (:func:`repro.core.graph.from_edges_mmap`) stream
@@ -182,15 +181,16 @@ def local_ptrs(g: CSRGraph, lo: int, hi: int, halo: np.ndarray):
 
 def owned_idx(g: CSRGraph, lo: int, hi: int):
     """Concatenated idx entries of the OWNED rows ``[lo, hi)`` only —
-    ``(out_block, nbr_block)`` int32 — the single host→device upload a
-    pool-mode shard pays (1/P of the graph; halo blocks arrive
+    ``(out_block, nbr_block, code_block)`` int32 — the single host→device
+    upload a pool-mode shard pays (1/P of the graph; halo blocks arrive
     device-to-device from their owners)."""
     verts = np.arange(lo, hi, dtype=np.int64)
-    out = _gather_rows(_host(g.arrays.out_ptr)[: g.n + 1].astype(np.int64),
-                       _host(g.arrays.out_idx), verts).astype(np.int32)
-    nbr = _gather_rows(_host(g.arrays.nbr_ptr)[: g.n + 1].astype(np.int64),
-                       _host(g.arrays.nbr_idx), verts).astype(np.int32)
-    return out, nbr
+    out_ptr = _host(g.arrays.out_ptr)[: g.n + 1].astype(np.int64)
+    nbr_ptr = _host(g.arrays.nbr_ptr)[: g.n + 1].astype(np.int64)
+    return tuple(_gather_rows(ptr, _host(idx), verts).astype(np.int32)
+                 for ptr, idx in ((out_ptr, g.arrays.out_idx),
+                                  (nbr_ptr, g.arrays.nbr_idx),
+                                  (nbr_ptr, g.arrays.nbr_code)))
 
 
 def build_local_arrays(g: CSRGraph, lo: int, hi: int,
@@ -217,9 +217,10 @@ def build_local_arrays(g: CSRGraph, lo: int, hi: int,
 
     out_ptr, out_idx = sub(g.arrays.out_ptr, g.arrays.out_idx)
     nbr_ptr, nbr_idx = sub(g.arrays.nbr_ptr, g.arrays.nbr_idx)
+    _, nbr_code = sub(g.arrays.nbr_ptr, g.arrays.nbr_code)
     nbr_deg = (nbr_ptr[1:] - nbr_ptr[:-1]).astype(np.int32)
     return GraphArrays(out_ptr=out_ptr, out_idx=out_idx, nbr_ptr=nbr_ptr,
-                       nbr_idx=nbr_idx, nbr_deg=nbr_deg)
+                       nbr_idx=nbr_idx, nbr_deg=nbr_deg, nbr_code=nbr_code)
 
 
 @dataclasses.dataclass(frozen=True)

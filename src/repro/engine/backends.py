@@ -230,8 +230,9 @@ def make_xla_stream_batch_fn(layout, config, stats: dict, chunk: int):
     return stream_batch_fn
 
 
-def _memo_tasks(plan, g: CSRGraph, key, build) -> "list[ChunkTask]":
-    """Per-plan memo of a host-derived chunk schedule.
+def _memo_tasks(plan, g: CSRGraph, key, build):
+    """Per-plan memo of a host-derived chunk schedule (whatever ``build``
+    returns: a task list, or the pallas pass's ``(tasks, probe_columns)``).
 
     The task list is a pure function of ``(graph, key)`` but costs O(m)
     host preprocessing (dyad enumeration, degree weights, sorts) — pay
@@ -499,10 +500,12 @@ def make_pallas_chunk_fn(layout, config, stats: dict):
     """Fused device chunk unit for the pallas backend.
 
     ``(arrays, n, su, sv, start, end, hi, lo; K, chunk, block, interpret)``:
-    slice the bucket-sorted dyad list, gather VMEM tiles and run the
-    census tile kernel into the ``triad_census`` accumulator slice, and
-    run every other op's generic batch kernel on the same chunk of dyads
-    — one dispatch, zero host staging (per-run ``once`` contributions are
+    slice the bucket-sorted dyad list, gather each dyad's two
+    direction-coded neighbour rows (the shorter endpoint's and the
+    longer's, told apart by ``arrays.nbr_deg``) and run the census tile
+    kernel into the ``triad_census`` accumulator slice, and run every
+    other op's generic batch kernel on the same chunk of dyads — one
+    dispatch, zero host staging (per-run ``once`` contributions are
     folded by the driver before the chunk loop).  Ops other than the
     census don't need the tiles, so the one expensive gather is paid
     exactly once per chunk for the whole op set.  ``stats['traces']``
@@ -530,11 +533,16 @@ def make_pallas_chunk_fn(layout, config, stats: dict):
                 hi, lo, rest(arrays, n, jnp.where(valid, u, 0),
                              jnp.where(valid, v, 1), valid))
         if census_sl is not None:
-            tiles = kops.gather_tiles_device(arrays, u, v, valid, K=K)
+            deg_u = jnp.where(valid, arrays.nbr_deg[u], 0)
+            deg_v = jnp.where(valid, arrays.nbr_deg[v], 0)
+            u_short = deg_u <= deg_v
+            tiles = kops.gather_tiles_device(
+                arrays, jnp.where(u_short, u, v), jnp.where(u_short, v, u),
+                valid, K=K)
             parts = census_tiles_pallas(
                 jnp.where(valid, u, SENTINEL), jnp.where(valid, v, SENTINEL),
-                n, *(tiles[k] for k in ("out_u", "in_u", "out_v", "in_v",
-                                        "nbr_u", "nbr_v")),
+                n, u_short, tiles["short"], tiles["long"],
+                jnp.minimum(deg_u, deg_v), jnp.maximum(deg_u, deg_v),
                 block=block, interpret=interpret, reduce=False)
 
             def fold(carry, p):
@@ -568,13 +576,8 @@ def _run_pallas_sync(plan, g: CSRGraph) -> np.ndarray:
         # padded (bucket-shaped) arrays: the layout-cached jitted once
         # kernel must see one shape per plan, not one per concrete graph.
         _once_sync(plan, counts, plan.padded_arrays(g), n_dev)
-    # transpose CSR, built once per run — tile building only, so skipped
-    # when no op uses the census tile kernel.
-    in_csr = ops.build_in_csr(g) if census_sl is not None else None
     deg = np.asarray(g.arrays.nbr_deg)
-    out_deg = np.diff(np.asarray(g.arrays.out_ptr))
-    need = np.maximum(np.maximum(deg[u], deg[v]),
-                      np.maximum(out_deg[u], out_deg[v]))
+    need = np.maximum(deg[u], deg[v])
     kmax = max(g.max_deg, 1)
     ks = sorted({min(max(int(k), 1), kmax) for k in cfg.buckets} | {kmax})
     chunk = max(block, (plan.chunk // block) * block)
@@ -602,20 +605,21 @@ def _run_pallas_sync(plan, g: CSRGraph) -> np.ndarray:
             if census_sl is None:
                 plan.stats["chunks"] += 1
                 continue
+            # padded dyads: SENTINEL endpoints, degree 0, blank tiles
             pad = (-len(uu)) % block
-            if pad:
-                uu = np.concatenate([uu, np.full(pad, SENTINEL, np.int32)])
-                vv = np.concatenate([vv, np.full(pad, SENTINEL, np.int32)])
-            tiles = ops.build_tiles(g, np.clip(uu, 0, g.n - 1).astype(np.int64),
-                                    np.clip(vv, 0, g.n - 1).astype(np.int64),
-                                    K, in_csr=in_csr)
-            if pad:  # padded dyads: blank their tiles
-                for t in tiles.values():
-                    t[-pad:] = SENTINEL
+            uu = np.concatenate([uu, np.full(pad, SENTINEL, np.int32)])
+            vv = np.concatenate([vv, np.full(pad, SENTINEL, np.int32)])
+            live = np.arange(len(uu)) < len(uu) - pad
+            deg_u = np.where(live, deg[np.where(live, uu, 0)], 0)
+            deg_v = np.where(live, deg[np.where(live, vv, 0)], 0)
+            u_short = deg_u <= deg_v
+            tiles = ops.build_tiles(g, np.where(u_short, uu, vv),
+                                    np.where(u_short, vv, uu), live, K)
             part = census_tiles_pallas(
-                jnp.asarray(uu), jnp.asarray(vv), g.n,
-                *(jnp.asarray(tiles[k]) for k in
-                  ("out_u", "in_u", "out_v", "in_v", "nbr_u", "nbr_v")),
+                jnp.asarray(uu), jnp.asarray(vv), g.n, jnp.asarray(u_short),
+                jnp.asarray(tiles["short"]), jnp.asarray(tiles["long"]),
+                jnp.asarray(np.minimum(deg_u, deg_v)),
+                jnp.asarray(np.maximum(deg_u, deg_v)),
                 block=block, interpret=interpret)
             counts[census_sl] += np.asarray(part, dtype=np.int64)
             plan.stats["chunks"] += 1
@@ -638,11 +642,11 @@ def run_pallas(plan, g: CSRGraph) -> np.ndarray:
     kmax = max(plan.meta.k, 1)
     ks = tuple(sorted({min(max(int(k), 1), kmax)
                        for k in cfg.buckets} | {kmax}))
-    # the tile kernel's whole support system — device-built transpose CSR,
-    # degree-bucket sort, and the host-derived bucket schedule — only
-    # exists for the census slice; a plan of generic ops skips all three.
+    # the tile kernel's support system — degree-bucket sort and the
+    # host-derived bucket schedule — only exists for the census slice; a
+    # plan of generic ops skips both.
     census_needed = "triad_census" in plan.layout.slices
-    arrays = plan.padded_arrays(g, with_in_csr=census_needed)
+    arrays = plan.padded_arrays(g)
     with span("enumerate"):
         du, dv = enumerate_dyads_device(arrays.nbr_ptr, arrays.nbr_idx,
                                         jnp.int32(g.m_nbr),
@@ -650,8 +654,7 @@ def run_pallas(plan, g: CSRGraph) -> np.ndarray:
         stream_u, stream_v = du, dv
         if census_needed:
             stream_u, stream_v, _ = sort_dyads_by_bucket(
-                arrays.nbr_deg, arrays.out_ptr, du, dv,
-                jnp.int32(g.n_dyads), ks=ks)
+                arrays.nbr_deg, du, dv, jnp.int32(g.n_dyads), ks=ks)
     n = jnp.int32(g.n)
     hi = lo = jnp.zeros(plan.layout.total_bins, jnp.int32)
     init = _once_device(plan, hi, lo, arrays, n)
@@ -665,8 +668,8 @@ def run_pallas(plan, g: CSRGraph) -> np.ndarray:
         # sort finished.  The counts are a pure function of the degree
         # arrays the host already owns, so derive them (and the per-dyad
         # tile-width needs, the dynamic schedule's cost model) host-side.
-        tasks = _pallas_bucket_tasks(plan, g, ks, chunk)
-        count_tiles(plan.stats, tasks, chunk)
+        tasks, probe = _pallas_bucket_tasks(plan, g, ks, chunk)
+        count_tiles(plan.stats, tasks, chunk, probe)
 
     def place(dev):
         ctx = (arrays, n, stream_u, stream_v)
@@ -682,24 +685,28 @@ def run_pallas(plan, g: CSRGraph) -> np.ndarray:
     return _acc_fetch(plan, hi, lo)
 
 
-def count_tiles(stats: dict, tasks, chunk: int) -> None:
+def count_tiles(stats: dict, tasks, chunk: int, probe_columns: int) -> None:
     """Count a census pass's tile work in ``stats``: ``tile_slots``, the
-    slots of the six ``(chunk, K)`` tiles each task gathers (padding
+    slots of the two ``(chunk, K)`` tiles each task gathers (padding
     included), ``gather_blocks``, the aligned blocks fetched to fill them
     (the gather's indices, :func:`repro.kernels.ops.gather_blocks_per_row`
-    per tile row), and ``dyads``, the live dyads those tiles hold."""
+    per tile row), ``dyads``, the live dyads those tiles hold, and
+    ``probe_columns``, the pass's Σ min(deg u, deg v): the short-row
+    columns the kernel's probe must walk."""
     from ..kernels.ops import gather_blocks_per_row
 
-    stats["tile_slots"] += sum(6 * chunk * t.key for t in tasks)
-    stats["gather_blocks"] += sum(6 * chunk * gather_blocks_per_row(t.key)
+    stats["tile_slots"] += sum(2 * chunk * t.key for t in tasks)
+    stats["gather_blocks"] += sum(2 * chunk * gather_blocks_per_row(t.key)
                                   for t in tasks)
     stats["dyads"] += sum(min(t.end, t.start + chunk) - t.start
                           for t in tasks)
+    stats["probe_columns"] += int(probe_columns)
 
 
-def _pallas_bucket_tasks(plan, g: CSRGraph, ks: tuple,
-                         chunk: int) -> "list[ChunkTask]":
-    """Per-bucket chunk schedule over the bucket-sorted dyad stream.
+def _pallas_bucket_tasks(plan, g: CSRGraph, ks: tuple, chunk: int
+                         ) -> "tuple[list[ChunkTask], int]":
+    """Per-bucket chunk schedule over the bucket-sorted dyad stream, and
+    the pass's probe columns (Σ over dyads of min(deg u, deg v)).
 
     Each task carries its bucket's tile width ``K`` (the pallas kernel's
     static specialization).  Static: the fixed-size grid within every
@@ -713,8 +720,11 @@ def _pallas_bucket_tasks(plan, g: CSRGraph, ks: tuple,
     """
     def build():
         dynamic = plan.config.schedule == "dynamic"
+        u, v = canonical_dyads(g)
+        deg = np.asarray(g.arrays.nbr_deg)
+        probe = int(np.minimum(deg[u], deg[v]).sum(dtype=np.int64))
         bucket_counts, need_sorted = host_bucket_schedule(
-            g, ks, with_needs=dynamic)
+            g, ks, with_needs=dynamic, dyads=(u, v))
         if dynamic:
             cum = np.concatenate([[0.0],
                                   np.cumsum(need_sorted, dtype=np.float64)])
@@ -734,7 +744,7 @@ def _pallas_bucket_tasks(plan, g: CSRGraph, ks: tuple,
                                     float(K * min(chunk, offset + c - s)), K)
                           for s in range(offset, offset + c, chunk)]
             offset += c
-        return tasks
+        return tasks, probe
 
     return _memo_tasks(plan, g, ("pallas", ks, chunk), build)
 

@@ -243,9 +243,8 @@ def _pallas_subset_schedule(plan, g: CSRGraph, u: np.ndarray, v: np.ndarray):
     D = len(u)
     if census_needed and D:
         deg = np.asarray(g.arrays.nbr_deg)
-        out_deg = np.diff(np.asarray(g.arrays.out_ptr)[: g.n + 1])
-        need = np.maximum(np.maximum(deg[u], deg[v]),
-                          np.maximum(out_deg[u], out_deg[v])).astype(np.int64)
+        need = np.maximum(deg[u], deg[v]).astype(np.int64)
+        probe = int(np.minimum(deg[u], deg[v]).sum(dtype=np.int64))
         ks_arr = np.asarray(ks, dtype=np.int64)
         b = (need[:, None] > ks_arr[None, :]).sum(1)
         order = np.lexsort((need, b))
@@ -270,7 +269,7 @@ def _pallas_subset_schedule(plan, g: CSRGraph, u: np.ndarray, v: np.ndarray):
                           for s in range(offset, offset + c, chunk)]
             offset += c
         from .backends import count_tiles
-        count_tiles(plan.stats, tasks, chunk)
+        count_tiles(plan.stats, tasks, chunk, probe)
     else:
         tasks = [t._replace(key=kmax)
                  for t in _subset_tasks(plan, g, u, v, chunk)]
@@ -283,18 +282,15 @@ def _subset_pallas(plan, g: CSRGraph, u: np.ndarray, v: np.ndarray, *,
     dyads mirrors the full pass's device sort, so every task dispatches an
     already-compiled ``K`` specialization of the tile kernel.
 
-    ``arrays``/``init``/``pad`` as in :func:`_subset_xla`; an ``arrays``
-    override must already carry the transpose CSR when the plan runs the
-    census tile kernel (the partitioned engine builds it per shard —
-    shard-local in-rows are complete because every in-arc source of a
-    kept endpoint is one of its neighbors, hence in the halo)."""
+    ``arrays``/``init``/``pad`` as in :func:`_subset_xla` (the
+    partitioned engine passes shard-local arrays: a kept row's
+    direction-coded entries are the global row's)."""
     from .backends import _once_device
 
     if g.n_dyads == 0:
         return _zeros(plan) if init is None else init
-    census_needed = "triad_census" in plan.layout.slices
     if arrays is None:
-        arrays = plan.padded_arrays(g, with_in_csr=census_needed)
+        arrays = plan.padded_arrays(g)
     n = jnp.int32(g.n)
     if init is None:
         init = _once_device(plan, *_zeros(plan), arrays, n)

@@ -152,7 +152,7 @@ def _shard_arrays(plan, g: CSRGraph, shard, geom: _Geometry) -> GraphArrays:
     from .plan import _pad_to
     local = build_local_arrays(g, shard.lo, shard.hi, shard.halo)
     m = plan.meta
-    arrays = GraphArrays(
+    return GraphArrays(
         out_ptr=jnp.asarray(_pad_to(local.out_ptr, m.n_bucket + 1,
                                     local.out_ptr[-1])),
         out_idx=jnp.asarray(_pad_to(local.out_idx, geom.m_out, 0)),
@@ -160,20 +160,8 @@ def _shard_arrays(plan, g: CSRGraph, shard, geom: _Geometry) -> GraphArrays:
                                     local.nbr_ptr[-1])),
         nbr_idx=jnp.asarray(_pad_to(local.nbr_idx, geom.m_nbr, 0)),
         nbr_deg=jnp.asarray(_pad_to(local.nbr_deg, m.n_bucket, 0)),
+        nbr_code=jnp.asarray(_pad_to(local.nbr_code, geom.m_nbr, 0)),
     )
-    if _census_in_csr(plan):
-        # shard-local transpose CSR — complete for kept rows, because an
-        # in-arc source of an endpoint is one of its neighbors (in-halo).
-        from ..kernels import ops
-        in_ptr, in_idx = ops.build_in_csr_device(arrays.out_ptr,
-                                                 arrays.out_idx)
-        arrays = arrays._replace(in_ptr=in_ptr, in_idx=in_idx)
-    return arrays
-
-
-def _census_in_csr(plan) -> bool:
-    return (plan.backend == "pallas" and plan.device_path
-            and "triad_census" in plan.layout.slices)
 
 
 def _once_init(plan, g: CSRGraph):
@@ -186,7 +174,7 @@ def _once_init(plan, g: CSRGraph):
     if not plan.layout.has_once:
         return _zeros(plan)
     from .backends import _once_device
-    arrays = plan.padded_arrays(g, with_in_csr=False)
+    arrays = plan.padded_arrays(g)
     return _once_device(plan, *_zeros(plan), arrays, jnp.int32(g.n))
 
 
@@ -232,13 +220,11 @@ def _stage_spill(u: np.ndarray, v: np.ndarray, scratch: str, tag: str):
 
 def _bytes_for(plan, m_out: int, m_nbr: int, dyad_slots: int) -> int:
     """int32 bytes of one resident census context with the given idx and
-    dyad-slot geometry: ptr/deg halves + idx arrays (+ transpose CSR on
-    the pallas census path) + the dyad stream + the hi/lo lanes."""
+    dyad-slot geometry: ptr/deg halves + idx and code arrays + the dyad
+    stream + the hi/lo lanes."""
     m = plan.meta
     b = 4 * (2 * (m.n_bucket + 1) + m.n_bucket)
-    b += 4 * (m_out + m_nbr)
-    if _census_in_csr(plan):
-        b += 4 * ((m.n_bucket + 1) + m_out)
+    b += 4 * (m_out + 2 * m_nbr)
     b += 2 * 4 * dyad_slots
     b += 2 * 4 * plan.layout.total_bins
     return int(b)
@@ -352,18 +338,24 @@ def _scatter_block(idx_arr, vals, start, n_valid):
 # pool mode: concurrent shard residency across the device pool
 # ---------------------------------------------------------------------------
 
+#: the compacted idx-like arrays of a shard context and the ptr array
+#: that lays each out (``nbr_code`` shares the undirected rows)
+_IDX_FIELDS = (("out_idx", "out_ptr"), ("nbr_idx", "nbr_ptr"),
+               ("nbr_code", "nbr_ptr"))
+
+
 def _stage_pool_shard(plan, g, shard, geom, u, v, dev):
     """Phase 1 of pool staging: ONE host→device put per shard carrying
-    the ptr halves (vertex-count-sized), the OWNED idx blocks (1/P of
-    the graph — owned rows occupy the contiguous span
-    ``[ptr[lo], ptr[hi])`` of the compacted idx layout) and the padded
-    dyad stream.  The idx arrays are zero-initialized on device and the
-    owned block scattered in; halo blocks arrive in phase 2, peer-to-peer
-    from their owners."""
+    the ptr halves (vertex-count-sized), the OWNED idx and code blocks
+    (1/P of the graph — owned rows occupy the contiguous span
+    ``[ptr[lo], ptr[hi])`` of the compacted layout) and the padded dyad
+    stream.  The idx-like arrays are zero-initialized on device and the
+    owned blocks scattered in; halo blocks arrive in phase 2,
+    peer-to-peer from their owners."""
     from .plan import _pad_to
     m = plan.meta
     out_ptr, nbr_ptr, nbr_deg = local_ptrs(g, shard.lo, shard.hi, shard.halo)
-    own_out, own_nbr = owned_idx(g, shard.lo, shard.hi)
+    owned = owned_idx(g, shard.lo, shard.hi)
     du = np.zeros(geom.pad, np.int32)
     dv = np.ones(geom.pad, np.int32)
     du[: len(u)] = u
@@ -371,21 +363,20 @@ def _stage_pool_shard(plan, g, shard, geom, u, v, dev):
     host = (_pad_to(out_ptr, m.n_bucket + 1, out_ptr[-1]),
             _pad_to(nbr_ptr, m.n_bucket + 1, nbr_ptr[-1]),
             _pad_to(nbr_deg, m.n_bucket, 0),
-            _pad_to(own_out, _next_pow2(max(len(own_out), 1)), 0),
-            _pad_to(own_nbr, _next_pow2(max(len(own_nbr), 1)), 0),
+            tuple(_pad_to(blk, _next_pow2(max(len(blk), 1)), 0)
+                  for blk in owned),
             np.int32(g.n), du, dv)
-    (d_optr, d_nptr, d_deg, d_oblk, d_nblk,
-     d_n, d_du, d_dv) = jax.device_put(host, dev)
-    out_idx = _scatter_block(_device_zeros(geom.m_out, dev), d_oblk,
-                             jnp.int32(int(out_ptr[shard.lo])),
-                             jnp.int32(len(own_out)))
-    nbr_idx = _scatter_block(_device_zeros(geom.m_nbr, dev), d_nblk,
-                             jnp.int32(int(nbr_ptr[shard.lo])),
-                             jnp.int32(len(own_nbr)))
-    return dict(dev=dev, n=d_n, du=d_du, dv=d_dv,
-                out_ptr=d_optr, nbr_ptr=d_nptr, nbr_deg=d_deg,
-                out_idx=out_idx, nbr_idx=nbr_idx,
-                host_out_ptr=out_ptr, host_nbr_ptr=nbr_ptr)
+    d_optr, d_nptr, d_deg, d_blks, d_n, d_du, d_dv = jax.device_put(host,
+                                                                    dev)
+    w = dict(dev=dev, n=d_n, du=d_du, dv=d_dv,
+             out_ptr=d_optr, nbr_ptr=d_nptr, nbr_deg=d_deg,
+             host_out_ptr=out_ptr, host_nbr_ptr=nbr_ptr)
+    sizes = {"out_ptr": geom.m_out, "nbr_ptr": geom.m_nbr}
+    for (field, ptr), d_blk, blk in zip(_IDX_FIELDS, d_blks, owned):
+        w[field] = _scatter_block(_device_zeros(sizes[ptr], dev), d_blk,
+                                  jnp.int32(int(w[f"host_{ptr}"][shard.lo])),
+                                  jnp.int32(len(blk)))
+    return w
 
 
 def _exchange_halos(plan, g, part, work, pstats):
@@ -401,71 +392,51 @@ def _exchange_halos(plan, g, part, work, pstats):
         for owner, ids in halo_by_owner(part.cuts, halo):
             ow = work.get(owner)
             spans = {}
-            for csr in ("out", "nbr"):
-                hp = w[f"host_{csr}_ptr"]
+            for ptr in ("out_ptr", "nbr_ptr"):
+                hp = w[f"host_{ptr}"]
                 blk = int(hp[ids[0]])
                 nv = int(hp[ids[-1] + 1]) - blk
-                spans[csr] = (blk, nv)
-            if ow is not None and ow["dev"] is not w["dev"]:
+                spans[ptr] = (blk, nv)
+            if ow is not None:
                 pad_ids = np.full(_next_pow2(max(len(ids), 1)),
                                   ids[-1], np.int32)
                 pad_ids[: len(ids)] = ids
                 d_ids = jax.device_put(pad_ids, ow["dev"])
                 n_ids = jnp.int32(len(ids))
                 vals = tuple(
-                    _gather_block(ow[f"{csr}_ptr"], ow[f"{csr}_idx"],
-                                  d_ids, n_ids,
-                                  out_len=_next_pow2(max(spans[csr][1], 1)))
-                    for csr in ("out", "nbr"))
-                vals = jax.device_put(vals, w["dev"])
-                pstats["d2d_puts"] += 1
-            elif ow is not None:
-                # same-device owner (P > pool width): gather in place,
-                # no transfer to count.
-                pad_ids = np.full(_next_pow2(max(len(ids), 1)),
-                                  ids[-1], np.int32)
-                pad_ids[: len(ids)] = ids
-                d_ids = jax.device_put(pad_ids, ow["dev"])
-                n_ids = jnp.int32(len(ids))
-                vals = tuple(
-                    _gather_block(ow[f"{csr}_ptr"], ow[f"{csr}_idx"],
-                                  d_ids, n_ids,
-                                  out_len=_next_pow2(max(spans[csr][1], 1)))
-                    for csr in ("out", "nbr"))
+                    _gather_block(ow[ptr], ow[field], d_ids, n_ids,
+                                  out_len=_next_pow2(max(spans[ptr][1], 1)))
+                    for field, ptr in _IDX_FIELDS)
+                if ow["dev"] is not w["dev"]:
+                    vals = jax.device_put(vals, w["dev"])
+                    pstats["d2d_puts"] += 1
+                # else: a same-device owner (P > pool width) gathers in
+                # place, no transfer to count.
             else:
                 # owner owns no dyads, so it was never staged: host rows
                 # (identical to any resident copy) upload directly.
                 ids64 = ids.astype(np.int64)
                 host_vals = []
-                for csr in ("out", "nbr"):
-                    ptr = _host(getattr(g.arrays, f"{csr}_ptr"))
-                    ptr = ptr[: g.n + 1].astype(np.int64)
-                    idx = getattr(g.arrays, f"{csr}_idx")
-                    rows = _gather_rows(ptr, idx, ids64).astype(np.int32)
+                for field, ptr in _IDX_FIELDS:
+                    hp = _host(getattr(g.arrays, ptr))[: g.n + 1]
+                    rows = _gather_rows(hp.astype(np.int64),
+                                        getattr(g.arrays, field),
+                                        ids64).astype(np.int32)
                     pad = np.zeros(_next_pow2(max(len(rows), 1)), np.int32)
                     pad[: len(rows)] = rows
                     host_vals.append(pad)
                 vals = jax.device_put(tuple(host_vals), w["dev"])
                 pstats["halo_host_puts"] = pstats.get("halo_host_puts",
                                                       0) + 1
-            for csr, mv in zip(("out", "nbr"), vals):
-                blk, nv = spans[csr]
-                w[f"{csr}_idx"] = _scatter_block(w[f"{csr}_idx"], mv,
-                                                 jnp.int32(blk),
-                                                 jnp.int32(nv))
+            for (field, ptr), mv in zip(_IDX_FIELDS, vals):
+                blk, nv = spans[ptr]
+                w[field] = _scatter_block(w[field], mv, jnp.int32(blk),
+                                          jnp.int32(nv))
 
 
 def _finish_pool_context(plan, w):
-    """Assemble one staged shard's executor context (and, on the pallas
-    census path, build the shard-local transpose CSR on its home device
-    from the now-complete out-CSR)."""
-    arrays = GraphArrays(out_ptr=w["out_ptr"], out_idx=w["out_idx"],
-                         nbr_ptr=w["nbr_ptr"], nbr_idx=w["nbr_idx"],
-                         nbr_deg=w["nbr_deg"])
-    if _census_in_csr(plan):
-        from ..kernels import ops
-        in_ptr, in_idx = ops.build_in_csr_device(w["out_ptr"], w["out_idx"])
-        arrays = arrays._replace(in_ptr=in_ptr, in_idx=in_idx)
+    """Assemble one staged shard's executor context."""
+    arrays = GraphArrays(**{f: w[f] for f in GraphArrays._fields})
     return (arrays, w["n"], w["du"], w["dv"])
 
 
@@ -656,6 +627,7 @@ def _mesh_pass(plan, g, part, geom, shard_lists, init, pstats):
         s_nptr = np.zeros((n_dev, m.n_bucket + 1), np.int32)
         s_nidx = np.zeros((n_dev, geom.m_nbr), np.int32)
         s_deg = np.zeros((n_dev, m.n_bucket), np.int32)
+        s_code = np.zeros((n_dev, geom.m_nbr), np.int32)
         su = np.zeros((n_dev, L), np.int32)
         sv = np.ones((n_dev, L), np.int32)
         sval = np.zeros((n_dev, L), bool)
@@ -668,6 +640,7 @@ def _mesh_pass(plan, g, part, geom, shard_lists, init, pstats):
                                 local.nbr_ptr[-1])
             s_nidx[d] = _pad_to(local.nbr_idx, geom.m_nbr, 0)
             s_deg[d] = _pad_to(local.nbr_deg, m.n_bucket, 0)
+            s_code[d] = _pad_to(local.nbr_code, geom.m_nbr, 0)
             su[d, : len(u)] = u
             sv[d, : len(v)] = v
             sval[d, : len(u)] = True
@@ -675,7 +648,8 @@ def _mesh_pass(plan, g, part, geom, shard_lists, init, pstats):
                              out_idx=jnp.asarray(s_oidx),
                              nbr_ptr=jnp.asarray(s_nptr),
                              nbr_idx=jnp.asarray(s_nidx),
-                             nbr_deg=jnp.asarray(s_deg))
+                             nbr_deg=jnp.asarray(s_deg),
+                             nbr_code=jnp.asarray(s_code))
         pstats["h2d_puts"] += 1  # one stacked staging per wave
         n = jnp.int32(g.n)
         dsu, dsv, dsval = jnp.asarray(su), jnp.asarray(sv), jnp.asarray(sval)

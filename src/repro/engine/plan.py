@@ -37,7 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.census import CensusResult
-from ..core.graph import CSRGraph, GraphArrays
+from ..core.graph import MAX_CODED_VERTICES, CSRGraph, GraphArrays
 from ..core.graph import next_pow2 as _next_pow2
 from ..core.reorder import compute_permutation, permute_graph
 from ..core.spans import span
@@ -167,7 +167,7 @@ class Plan:
                       "delta_runs": 0, "delta_fulls": 0,
                       "delta_affected": 0, "delta_chunks": 0, "reorders": 0,
                       "tile_slots": 0, "gather_blocks": 0, "dyads": 0,
-                      "bytes_staged": 0,
+                      "probe_columns": 0, "bytes_staged": 0,
                       "task_memo_hits": 0, "task_memo_misses": 0,
                       "faults": dict(chunk_failures=0, retries=0,
                                      device_losses=0, quarantines=0,
@@ -212,6 +212,12 @@ class Plan:
         try:
             if fplan is not None and fplan.compile_fails(backend):
                 raise InjectedFault(f"injected {backend} compile failure")
+            if backend == "pallas" and meta.n_bucket > MAX_CODED_VERTICES:
+                raise ValueError(
+                    f"the pallas census kernel packs vertex ids into "
+                    f"direction-coded rows (nbr_code), exact for at most "
+                    f"{MAX_CODED_VERTICES} vertices; this plan's vertex "
+                    f"bucket is {meta.n_bucket}")
             self._fn = self._build_fn(backend)
         except Exception as e:
             # pallas→xla is the only compile-fallback rung: the xla unit
@@ -297,37 +303,20 @@ class Plan:
             nbr_ptr=_pad_to(nbr_ptr, m.n_bucket + 1, nbr_ptr[-1]),
             nbr_idx=_pad_to(np.asarray(a.nbr_idx), m.m_nbr_bucket, 0),
             nbr_deg=_pad_to(np.asarray(a.nbr_deg), m.n_bucket, 0),
+            nbr_code=_pad_to(np.asarray(a.nbr_code), m.m_nbr_bucket, 0),
         )
 
-    def padded_arrays(self, g: CSRGraph, *,
-                      with_in_csr: Optional[bool] = None) -> GraphArrays:
+    def padded_arrays(self, g: CSRGraph) -> GraphArrays:
         """Device arrays padded to the metadata buckets (shape-stable).
 
         Padded ptr rows repeat the last offset (empty rows: binary search
-        sees lo == hi and never matches); padded idx/deg entries are inert.
-
-        ``with_in_csr`` additionally populates the transpose (in-arc) CSR
-        fields, built **on device** by
-        :func:`repro.kernels.ops.build_in_csr_device` — once per run, no
-        host round trip.  Default: only for the device-resident pallas
-        path when an op actually uses the census tile kernel, the one
-        consumer of in-arc tiles.
+        sees lo == hi and never matches); padded idx/deg/code entries are
+        inert.
         """
         with span("stage"):
             host = self.padded_arrays_host(g)
-            self.stats["bytes_staged"] += sum(v.nbytes for v in host
-                                              if v is not None)
-            arrays = GraphArrays(
-                **{f: (None if v is None else jnp.asarray(v))
-                   for f, v in zip(GraphArrays._fields, host)})
-            if with_in_csr is None:
-                with_in_csr = (self.backend == "pallas" and self.device_path
-                               and "triad_census" in self.layout.slices)
-            if with_in_csr:
-                from ..kernels import ops
-                in_ptr, in_idx = ops.build_in_csr_device(arrays.out_ptr,
-                                                         arrays.out_idx)
-                arrays = arrays._replace(in_ptr=in_ptr, in_idx=in_idx)
+            self.stats["bytes_staged"] += sum(v.nbytes for v in host)
+            arrays = GraphArrays(*(jnp.asarray(v) for v in host))
         return arrays
 
     # -- locality-aware reordering -------------------------------------------
@@ -540,6 +529,7 @@ class Plan:
             nbr_ptr=jax.ShapeDtypeStruct((m.n_bucket + 1,), jnp.int32),
             nbr_idx=jax.ShapeDtypeStruct((m.m_nbr_bucket,), jnp.int32),
             nbr_deg=jax.ShapeDtypeStruct((m.n_bucket,), jnp.int32),
+            nbr_code=jax.ShapeDtypeStruct((m.m_nbr_bucket,), jnp.int32),
         )
         n = jax.ShapeDtypeStruct((), jnp.int32)
         if self.backend == "distributed":
@@ -611,12 +601,10 @@ class CensusPlan:
         """
         return [r["triad_census"] for r in self._plan.run_batch(graphs)]
 
-    def padded_arrays(self, g: CSRGraph, *,
-                      with_in_csr: Optional[bool] = None) -> GraphArrays:
+    def padded_arrays(self, g: CSRGraph) -> GraphArrays:
         """Device arrays padded to the metadata buckets (shape-stable);
-        see :meth:`Plan.padded_arrays` for padding + transpose-CSR
-        semantics."""
-        return self._plan.padded_arrays(g, with_in_csr=with_in_csr)
+        see :meth:`Plan.padded_arrays` for padding semantics."""
+        return self._plan.padded_arrays(g)
 
     def padded_arrays_host(self, g: CSRGraph) -> GraphArrays:
         """Bucket-padded arrays as host numpy (no device transfer); see

@@ -10,47 +10,49 @@ import numpy as np
 from ..core.triad_table import TRIAD_TABLE_64
 
 
-def census_tiles_ref(out_u, in_u, out_v, in_v, nbr_u, nbr_v, u, v, n,
-                     sentinel=jnp.int32(2**30)):
+def census_tiles_ref(code_u, code_v, u, v, n, sentinel=jnp.int32(2**30)):
     """Oracle for the triad-census tile kernel.
 
-    All tile args: (D, K) int32 padded with ``sentinel``; u, v: (D,).
-    Returns (16,) int64-safe int32 histogram of dyadic+connected triads
-    (null triads come from the closed form outside).
+    ``code_u``/``code_v``: (D, K) int32 direction-coded rows of u and v
+    (``GraphArrays.nbr_code``: ``4·w + dir``, bit 0 = arc from the row's
+    vertex to w, bit 1 = arc from w), padded with ``sentinel``; u, v: (D,).
+    Every direction is looked up by an all-pairs compare against the rows,
+    independent of the kernel's short/long walk.  Returns (16,) int32
+    histogram of dyadic+connected triads (null triads come from the
+    closed form outside).
     """
+    def unpack(code):
+        real = code != sentinel
+        return jnp.where(real, code >> 2, -1), jnp.where(real, code & 3, 0)
 
-    def member(cand, rows):
-        return (cand[:, :, None] == rows[:, None, :]).any(-1)
+    ids_u, dir_u = unpack(code_u)
+    ids_v, dir_v = unpack(code_v)
 
-    valid_u = nbr_u != sentinel
-    valid_v = nbr_v != sentinel
+    def lookup(cand, ids, dirs):  # direction of each candidate in a row
+        hit = cand[:, :, None] == ids[:, None, :]
+        return jnp.where(hit, dirs[:, None, :], 0).sum(-1)
+
     # S = N(u) ∪ N(v) \ {u, v}
-    mu = valid_u & (nbr_u != v[:, None])
-    mv = valid_v & (nbr_v != u[:, None])
-    dup = member(nbr_v, nbr_u) & mv
-    mv_only = mv & ~dup
+    mu = (dir_u != 0) & (ids_u != v[:, None])
+    mv = (dir_v != 0) & (ids_v != u[:, None])
+    mv_only = mv & (lookup(ids_v, ids_u, dir_u) == 0)
     s_size = mu.sum(1) + mv_only.sum(1)
 
-    e_uv = member(v[:, None], out_u)[:, 0]
-    e_vu = member(u[:, None], out_v)[:, 0]
-    dyad_code = e_uv.astype(jnp.int32) + 2 * e_vu.astype(jnp.int32)
+    dyad_code = lookup(v[:, None], ids_u, dir_u)[:, 0]
     dyad_type = jnp.where(dyad_code == 3, 2, 1)
     dyadic = n - s_size - 2
 
     def codes(cand, canon):
-        c = dyad_code[:, None]
-        c = c + 4 * member(cand, out_u).astype(jnp.int32)
-        c = c + 8 * member(cand, in_u).astype(jnp.int32)
-        c = c + 16 * member(cand, out_v).astype(jnp.int32)
-        c = c + 32 * member(cand, in_v).astype(jnp.int32)
+        c = (dyad_code[:, None] + 4 * lookup(cand, ids_u, dir_u)
+             + 16 * lookup(cand, ids_v, dir_v))
         t = jnp.asarray(TRIAD_TABLE_64)[c]
         return jnp.where(canon, t, 0), canon
 
-    canon_u = mu & (nbr_u > v[:, None])
-    canon_v = mv_only & ((nbr_v > v[:, None]) |
-                         ((nbr_v > u[:, None]) & (nbr_v < v[:, None])))
-    t_u, m_u = codes(nbr_u, canon_u)
-    t_v, m_v = codes(nbr_v, canon_v)
+    canon_u = mu & (ids_u > v[:, None])
+    canon_v = mv_only & ((ids_v > v[:, None]) |
+                         ((ids_v > u[:, None]) & (ids_v < v[:, None])))
+    t_u, m_u = codes(ids_u, canon_u)
+    t_v, m_v = codes(ids_v, canon_v)
     counts = jnp.zeros(16, jnp.int32)
     counts = counts.at[t_u.reshape(-1)].add(m_u.reshape(-1).astype(jnp.int32))
     counts = counts.at[t_v.reshape(-1)].add(m_v.reshape(-1).astype(jnp.int32))

@@ -92,8 +92,7 @@ def test_host_bucket_schedule_matches_device_sort():
                                         jnp.int32(g.m_nbr),
                                         out_size=max(g.n_dyads, 1))
         _, _, counts_dev = sort_dyads_by_bucket(
-            g.arrays.nbr_deg, g.arrays.out_ptr, du, dv,
-            jnp.int32(g.n_dyads), ks=ks)
+            g.arrays.nbr_deg, du, dv, jnp.int32(g.n_dyads), ks=ks)
         counts, need_sorted = host_bucket_schedule(g, ks)
         assert counts.tolist() == np.asarray(counts_dev).tolist()
         assert counts.sum() == g.n_dyads == len(need_sorted)

@@ -22,25 +22,119 @@ def test_census_kernel_matches_brute_force(seed, block, buckets):
     assert (got == want).all(), (got, want)
 
 
+def _run_kernel(g, u, v, u_short, K, block, pad=0):
+    """The tile kernel over dyads (u, v) with the given short sides, in
+    interpret mode, plus ``pad`` padded dyads (SENTINEL endpoints, blank
+    tiles, length 0)."""
+    deg = np.asarray(g.arrays.nbr_deg)
+    live = np.arange(len(u) + pad) < len(u)
+    u = np.concatenate([u, np.full(pad, SENTINEL)]).astype(np.int32)
+    v = np.concatenate([v, np.full(pad, SENTINEL)]).astype(np.int32)
+    u_short = np.concatenate([u_short, np.ones(pad, bool)])
+    s_rows = np.where(u_short, u, v)
+    l_rows = np.where(u_short, v, u)
+    tiles = ops.build_tiles(g, s_rows, l_rows, live, K)
+    s_len = np.where(live, deg[np.where(live, s_rows, 0)], 0)
+    l_len = np.where(live, deg[np.where(live, l_rows, 0)], 0)
+    return census_tiles_pallas(
+        jnp.asarray(u), jnp.asarray(v), g.n, jnp.asarray(u_short),
+        jnp.asarray(tiles["short"]), jnp.asarray(tiles["long"]),
+        jnp.asarray(s_len), jnp.asarray(l_len), block=block,
+        interpret=True)
+
+
+def _oracle(g, u, v, K):
+    t = ops.build_tiles(g, u, v, np.ones(len(u), bool), K)
+    return ref.census_tiles_ref(jnp.asarray(t["short"]), jnp.asarray(t["long"]),
+                                jnp.asarray(u), jnp.asarray(v), g.n)
+
+
 @pytest.mark.parametrize("width", [None, 128, 200, 256])
 def test_census_kernel_matches_tile_oracle(width):
-    """Kernel vs ref.census_tiles_ref on identical random tiles, at the
-    graph's own tile width (below one 128-lane window), one full window,
-    a width the kernel pads up to 256, and two full windows."""
+    """Kernel vs ref.census_tiles_ref on identical dyads, at the graph's
+    own tile width (below one 128-lane window), one full window, a width
+    the kernel pads up to 256, and two full windows; the short side is
+    the smaller-degree endpoint, as the engine picks it."""
     g = generators.erdos_renyi(60, 240, seed=3)
     from repro.core.census import canonical_dyads
     u, v = canonical_dyads(g)
     D = (len(u) // 16) * 16
     u, v = u[:D].astype(np.int32), v[:D].astype(np.int32)
-    K = width or max(g.max_deg, g.max_out_deg)
-    tiles = ops.build_tiles(g, u.astype(np.int64), v.astype(np.int64), K)
-    args = [jnp.asarray(tiles[k]) for k in
-            ("out_u", "in_u", "out_v", "in_v", "nbr_u", "nbr_v")]
-    want = ref.census_tiles_ref(*args, jnp.asarray(u), jnp.asarray(v), g.n)
-    # oracle takes (out_u, in_u, ... , u, v, n) in different arg order
-    got = census_tiles_pallas(jnp.asarray(u), jnp.asarray(v), g.n, *args,
-                              block=16, interpret=True)
+    K = width or g.max_deg
+    deg = np.asarray(g.arrays.nbr_deg)
+    want = _oracle(g, u, v, K)
+    got = _run_kernel(g, u, v, deg[u] <= deg[v], K, block=16)
     assert (np.asarray(got) == np.asarray(want)).all()
+
+
+def _probe_graph():
+    """Hub 0 (degree 300: rows cross two 128-lane boundaries), vertices
+    1-4 of degree exactly 1, 127, 128 and 129, arcs of every direction
+    (out only, in only, mutual) on all of them, and a random sprinkle of
+    arcs among the rest that closes triangles."""
+    from repro.core.graph import from_edges
+    n = 700
+    rng = np.random.default_rng(11)
+    src, dst = [], []
+
+    def attach(x, k):
+        for i, w in enumerate(rng.choice(np.arange(10, n), k,
+                                         replace=False)):
+            kind = i % 3  # 0: x -> w, 1: w -> x, 2: both
+            if kind != 1:
+                src.append(x), dst.append(w)
+            if kind != 0:
+                src.append(w), dst.append(x)
+
+    attach(0, 300)
+    for x, k in zip((1, 2, 3, 4), (1, 127, 128, 129)):
+        attach(x, k)
+    a, b = rng.integers(10, n, 1500), rng.integers(10, n, 1500)
+    src += list(a) + list(b[:300])
+    dst += list(b) + list(a[:300])  # 300 of them mutual
+    return from_edges(n, np.array(src), np.array(dst))
+
+
+def _probe_dyads(g, group):
+    from repro.core.census import canonical_dyads
+    u, v = canonical_dyads(g)
+    deg = np.asarray(g.arrays.nbr_deg)
+    rng = np.random.default_rng(len(group))
+    if group == "hub_leaf":  # the hub against its smallest neighbours
+        sel = np.flatnonzero(u == 0)
+        sel = sel[np.argsort(deg[v[sel]], kind="stable")][:48]
+    elif group == "widths":  # every row of degree 1, 127, 128, 129
+        sel = np.flatnonzero((u >= 1) & (u <= 4))
+        sel = sel[rng.permutation(len(sel))][:48]
+        sel = np.union1d(sel, np.flatnonzero((u >= 1) & (u <= 4)
+                                             & (deg[u] <= 1)))
+    else:  # mutual dyads, with the hub's among them
+        from repro.core.graph import dense_adjacency
+        adj = dense_adjacency(g)
+        mutual = np.flatnonzero(adj[u, v] & adj[v, u])
+        sel = np.union1d(mutual[u[mutual] == 0][:16],
+                         mutual[rng.permutation(len(mutual))][:32])
+    return u[sel].astype(np.int32), v[sel].astype(np.int32)
+
+
+@pytest.mark.parametrize("side", ["u", "v"])
+@pytest.mark.parametrize("group", ["hub_leaf", "widths", "mutual"])
+def test_census_kernel_probe_cases(group, side):
+    """Hub against leaf, rows of width 1/127/128/129 (around a 128-lane
+    boundary) and mutual dyads, with either endpoint's row walked as the
+    short one, and padded dyads after them: the kernel's count equals
+    the oracle's over the live dyads."""
+    g = _probe_graph()
+    deg = np.asarray(g.arrays.nbr_deg)
+    assert deg[0] == 300 and list(deg[1:5]) == [1, 127, 128, 129]
+    u, v = _probe_dyads(g, group)
+    assert len(u) >= 16
+    K = 384
+    want = _oracle(g, u, v, K)
+    u_short = np.full(len(u), side == "u")
+    pad = (-len(u)) % 16 + 16
+    got = _run_kernel(g, u, v, u_short, K, block=16, pad=pad)
+    assert (np.asarray(got) == np.asarray(want)).all(), (got, want)
 
 
 @pytest.mark.parametrize("B,T,H,Hkv,D,chunk,win,dtype", [
@@ -100,8 +194,9 @@ def _gather_test_graph():
 
 @pytest.mark.parametrize("K", [1, 32, 128, 200, 512, 4096])
 def test_device_tile_gather_matches_host_tiles(K):
-    """gather_tiles_device == build_tiles bit for bit, and rows with
-    valid == False come back all-SENTINEL."""
+    """gather_tiles_device == build_tiles bit for bit on the
+    direction-coded rows, and rows with valid == False come back
+    all-SENTINEL."""
     g = _gather_test_graph()
     n = g.n
     rng = np.random.default_rng(K)
@@ -110,24 +205,20 @@ def test_device_tile_gather_matches_host_tiles(K):
     u = rows.astype(np.int64)
     v = rng.permutation(rows).astype(np.int64)
     valid = np.arange(len(rows)) % 7 != 6
-    in_ptr, in_idx = ops.build_in_csr_device(g.arrays.out_ptr,
-                                             g.arrays.out_idx)
-    arrays = g.arrays._replace(in_ptr=in_ptr, in_idx=in_idx)
     # the rows cover what the block gather must get right
-    for ptr, idx in ((g.arrays.out_ptr, g.arrays.out_idx),
-                     (in_ptr, in_idx), (g.arrays.nbr_ptr, g.arrays.nbr_idx)):
-        ptr = np.asarray(ptr)
-        start, deg = ptr[rows], ptr[rows + 1] - ptr[rows]
-        last = (len(idx) - 1) // ops.LANES * ops.LANES
-        assert ((start >= last) & (deg > 0) & valid).any()
-        assert ((deg == 0) & valid).any()
-        assert len(set(start[valid] % ops.LANES)) > 16
-    assert (np.diff(np.asarray(g.arrays.out_ptr))[rows[valid]] == K).any()
+    ptr = np.asarray(g.arrays.nbr_ptr)
+    start, deg = ptr[rows], ptr[rows + 1] - ptr[rows]
+    last = (len(g.arrays.nbr_code) - 1) // ops.LANES * ops.LANES
+    assert ((start >= last) & (deg > 0) & valid).any()
+    assert ((deg == 0) & valid).any()
+    assert len(set(start[valid] % ops.LANES)) > 16
+    assert (deg[valid] == K).any()
 
-    got = ops.gather_tiles_device(arrays, jnp.asarray(u, jnp.int32),
+    got = ops.gather_tiles_device(g.arrays, jnp.asarray(u, jnp.int32),
                                   jnp.asarray(v, jnp.int32),
                                   jnp.asarray(valid), K=K)
-    want = ops.build_tiles(g, u, v, K)
+    want = ops.build_tiles(g, u, v, valid, K)
+    assert set(got) == set(want) == {"short", "long"}
     for name, tile in want.items():
         tile_got = np.asarray(got[name])
         assert tile_got.shape == tile.shape == (len(rows), K)

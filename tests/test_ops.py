@@ -150,7 +150,7 @@ def test_fused_device_path_matches_sync_baseline(backend):
 
 def test_pallas_noncensus_plan_skips_tile_machinery():
     """A pallas plan with no census-kernel op must not pay the tile
-    kernel's support system: no bucket sort, no transpose CSR — results
+    kernel's support system: no bucket schedule, no tiles — results
     still match the references (and, like every device path, exactly
     one sync)."""
     g = generators.rmat(6, edge_factor=4, seed=0)
@@ -158,8 +158,8 @@ def test_pallas_noncensus_plan_skips_tile_machinery():
     plan = compile(g, ("dyad_census", "degree_stats"), cfg)
     res = plan.run(g)
     assert plan.stats["host_syncs"] == 1
-    arrays = plan.padded_arrays(g)
-    assert arrays.in_ptr is None  # transpose CSR skipped
+    # no tile gathered, no probe column walked
+    assert plan.stats["tile_slots"] == plan.stats["probe_columns"] == 0
     for name in ("dyad_census", "degree_stats"):
         _assert_result_equal(res[name], get_op(name).reference(g), ctx=name)
 

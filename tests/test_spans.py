@@ -12,6 +12,8 @@ import pytest
 
 import jax
 from repro.core import generators
+from repro.core.census import canonical_dyads
+from repro.core.delta import affected_dyads
 from repro.core.graph import arcs_host, from_edges
 from repro.engine import EngineConfig, compile
 
@@ -95,8 +97,17 @@ def test_chunk_spans_carry_their_bucket_and_bounds(traced):
 
 
 def _blocks(tasks, chunk: int) -> int:
-    """Aligned 128-lane blocks the six tiles of every task fetch."""
-    return sum(6 * chunk * (-(-t.key // 128) + 1) for t in tasks)
+    """Aligned 128-lane blocks the two tiles of every task fetch."""
+    return sum(2 * chunk * (-(-t.key // 128) + 1) for t in tasks)
+
+
+def _probe_columns(g, u, v) -> int:
+    """Σ min(deg u, deg v) over the dyads, from the arc list itself."""
+    nbrs = [set() for _ in range(g.n)]
+    for a, b in zip(*arcs_host(g)):
+        nbrs[a].add(b)
+        nbrs[b].add(a)
+    return sum(min(len(nbrs[a]), len(nbrs[b])) for a, b in zip(u, v))
 
 
 def test_counters_agree_with_the_plans_own_schedule():
@@ -104,14 +115,18 @@ def test_counters_agree_with_the_plans_own_schedule():
     _, plan, _ = _census(arcs)
     before = dict(plan.stats)
     g, _, _ = _census(arcs)          # a fresh graph: the memo misses
-    tasks, = [ts for ref, ts in plan._task_memo.values() if ref() is g]
+    (tasks, probe), = [ts for ref, ts in plan._task_memo.values()
+                       if ref() is g]
     chunk = max(CONFIG.resolve_block(),
                 plan.chunk // CONFIG.resolve_block()
                 * CONFIG.resolve_block())
     delta = {k: plan.stats[k] - before[k]
              for k in ("tile_slots", "gather_blocks", "dyads",
-                       "bytes_staged", "task_memo_hits", "task_memo_misses")}
-    assert delta["tile_slots"] == sum(6 * chunk * t.key for t in tasks)
+                       "probe_columns", "bytes_staged", "task_memo_hits",
+                       "task_memo_misses")}
+    assert delta["tile_slots"] == sum(2 * chunk * t.key for t in tasks)
+    assert delta["probe_columns"] == probe == _probe_columns(
+        g, *canonical_dyads(g))
     assert delta["gather_blocks"] == _blocks(tasks, chunk)
     assert delta["dyads"] == sum(min(t.end, t.start + chunk) - t.start
                                  for t in tasks) == g.n_dyads
@@ -152,12 +167,19 @@ def test_gather_blocks_count_a_delta_pass(monkeypatch):
         return run(tasks, **kw)
 
     monkeypatch.setattr(plan.executor, "run", spy)
-    before = plan.stats["gather_blocks"]
+    before = dict(plan.stats)
     delta = GraphDelta(edges_added=[(0, 9), (3, 17)],
                        edges_removed=[(int(arcs[1][0]), int(arcs[2][0]))])
     res = plan.apply_delta(g, delta, raw)
     assert res.mode == "delta" and dispatched
-    assert plan.stats["gather_blocks"] - before == _blocks(dispatched, chunk)
+    assert plan.stats["gather_blocks"] - before["gather_blocks"] == _blocks(
+        dispatched, chunk)
+    assert plan.stats["tile_slots"] - before["tile_slots"] == sum(
+        2 * chunk * t.key for t in dispatched)
+    # both subset passes walk the short row of every affected dyad
+    want = sum(_probe_columns(graph, *affected_dyads(graph, delta))
+               for graph in (g, res.graph))
+    assert plan.stats["probe_columns"] - before["probe_columns"] == want
 
 
 # span -> the span it opens inside, for one subscribed mutation on the

@@ -62,16 +62,28 @@ def _spec(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-@pytest.mark.parametrize("K", [32, 128, 512, 8192])
+@pytest.mark.parametrize("K", [32, 128, 512, 4096, 8192])
 def test_census_kernel_compiles_for_v5e(one_chip, K):
     D = 256
     vec = _spec((D,), jnp.int32, one_chip)
+    flag = _spec((D,), jnp.bool_, one_chip)
     tile = _spec((D, K), jnp.int32, one_chip)
     n = _spec((), jnp.int32, one_chip)
-    fn = jax.jit(lambda u, v, n, *t: census_tiles_pallas(
-        u, v, n, *t, block=32, interpret=False, reduce=False))
-    compiled = fn.lower(vec, vec, n, *[tile] * 6).compile()
+    fn = jax.jit(lambda u, v, n, us, s, l, sl, ll: census_tiles_pallas(
+        u, v, n, us, s, l, sl, ll, block=32, interpret=False, reduce=False))
+    compiled = fn.lower(vec, vec, n, flag, tile, tile, vec, vec).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _arrays(m, sharding):
+    i32 = jnp.int32
+    return GraphArrays(
+        out_ptr=_spec((m.n_bucket + 1,), i32, sharding),
+        out_idx=_spec((m.m_out_bucket,), i32, sharding),
+        nbr_ptr=_spec((m.n_bucket + 1,), i32, sharding),
+        nbr_idx=_spec((m.m_nbr_bucket,), i32, sharding),
+        nbr_deg=_spec((m.n_bucket,), i32, sharding),
+        nbr_code=_spec((m.m_nbr_bucket,), i32, sharding))
 
 
 def _gather_index_counts(hlo: str) -> list:
@@ -87,24 +99,16 @@ def _gather_index_counts(hlo: str) -> list:
     return counts
 
 
-def test_tile_gather_fetches_blocks_for_v5e(one_chip):
-    """The top bucket's tile gather (chunk 8192, K = 4096) compiles to
-    block gathers with no loop: no gather takes more indices than one per
-    aligned block of every tile row."""
-    chunk, K = 8192, 4096
-    m = EATSR_META
-    i32 = jnp.int32
-    ptr = _spec((m.n_bucket + 1,), i32, one_chip)
-    out_idx = _spec((m.m_out_bucket,), i32, one_chip)
-    arrays = GraphArrays(
-        out_ptr=ptr, out_idx=out_idx, nbr_ptr=ptr,
-        nbr_idx=_spec((m.m_nbr_bucket,), i32, one_chip),
-        nbr_deg=_spec((m.n_bucket,), i32, one_chip),
-        in_ptr=ptr, in_idx=out_idx)
-    rows = _spec((chunk,), i32, one_chip)
+@pytest.mark.parametrize("K", [32, 128, 512, 4096, 8192])
+def test_tile_gather_fetches_blocks_for_v5e(one_chip, K):
+    """The two direction-coded tiles of a chunk of 8192 dyads compile to
+    block gathers with no loop: no gather takes more indices than one
+    per aligned block of every tile row."""
+    chunk = 8192
+    rows = _spec((chunk,), jnp.int32, one_chip)
     valid = _spec((chunk,), jnp.bool_, one_chip)
-    hlo = gather_tiles_device.lower(arrays, rows, rows, valid,
-                                    K=K).compile().as_text()
+    hlo = gather_tiles_device.lower(_arrays(EATSR_META, one_chip), rows,
+                                    rows, valid, K=K).compile().as_text()
     assert " while(" not in hlo
     counts = _gather_index_counts(hlo)
     assert counts and max(counts) <= chunk * gather_blocks_per_row(K), counts
@@ -118,14 +122,7 @@ def test_fused_pallas_chunk_unit_fits_v5e(one_chip):
     fn = make_pallas_chunk_fn(layout, config, {"traces": 0})
     m = EATSR_META
     i32 = jnp.int32
-    arrays = GraphArrays(
-        out_ptr=_spec((m.n_bucket + 1,), i32, one_chip),
-        out_idx=_spec((m.m_out_bucket,), i32, one_chip),
-        nbr_ptr=_spec((m.n_bucket + 1,), i32, one_chip),
-        nbr_idx=_spec((m.m_nbr_bucket,), i32, one_chip),
-        nbr_deg=_spec((m.n_bucket,), i32, one_chip),
-        in_ptr=_spec((m.n_bucket + 1,), i32, one_chip),
-        in_idx=_spec((m.m_out_bucket,), i32, one_chip))
+    arrays = _arrays(m, one_chip)
     scalar = _spec((), i32, one_chip)
     dyads = _spec((m.m_nbr_bucket // 2,), i32, one_chip)
     acc = _spec((layout.total_bins,), i32, one_chip)

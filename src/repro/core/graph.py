@@ -78,58 +78,53 @@ class CSRGraph:
         return self.m_nbr // 2
 
 
-def _build_csr(n: int, rows: np.ndarray, cols: np.ndarray):
-    """Sorted CSR from (row, col) pairs; rows/cols must be deduplicated."""
-    order = np.lexsort((cols, rows))
-    rows, cols = rows[order], cols[order]
-    ptr = np.zeros(n + 1, dtype=np.int64)
-    np.add.at(ptr, rows + 1, 1)
-    ptr = np.cumsum(ptr)
-    return ptr.astype(np.int32), cols.astype(np.int32)
+def _row_ptr(rows: np.ndarray, n: int) -> np.ndarray:
+    """int32 CSR row pointers of ``n`` rows from sorted row ids."""
+    ptr = np.zeros(n + 1, np.int32)
+    ptr[1:] = np.cumsum(np.bincount(rows, minlength=n))
+    return ptr
 
 
 def _build_host_arrays(n: int, src, dst, *, directed: bool = True):
     """The host-side (numpy) half of :func:`from_edges`: canonicalize the
     arc list and build both CSRs.  Returns ``(host GraphArrays, m, m_nbr,
     max_deg, max_out_deg)`` — shared by the device-resident and
-    memory-mapped constructors so both are canonical over arc sets."""
+    memory-mapped constructors so both are canonical over arc sets.
+
+    Two sorts in all: ``np.unique`` puts the distinct arc keys in CSR
+    order, and one sort of tagged keys gives the neighbour rows.  The
+    tagged keys reach ``4·n² - 1``, which uint64 holds for every ``n`` an
+    int32 id can name."""
     src = np.asarray(src, dtype=np.int64)
     dst = np.asarray(dst, dtype=np.int64)
-    if src.size:
-        keep = src != dst
-        src, dst = src[keep], dst[keep]
-    if not directed and src.size:
+    if not directed:
         src, dst = np.concatenate([src, dst]), np.concatenate([dst, src])
-    # dedup directed arcs
-    if src.size:
-        key = src * np.int64(n) + dst
-        _, uniq = np.unique(key, return_index=True)
-        src, dst = src[uniq], dst[uniq]
-    out_ptr, out_idx = _build_csr(n, src, dst)
+    n64 = np.int64(n)
+    keep = src != dst
+    # the distinct arcs as sorted keys src·n + dst: the out-CSR in order
+    key = np.unique(src[keep] * n64 + dst[keep])
+    src, out_idx = np.divmod(key, n64)
 
     # undirected open neighborhoods, each entry with its direction bits:
-    # every arc seen from its source (bit 0, out) and from its target
-    # (bit 1, in).  The arcs are sorted by (src, dst) now, so the two runs
-    # of keys are sorted and one stable sort merges them; a mutual pair
-    # gives two equal keys, whose bits add.
-    fwd = src * np.int64(n) + dst
-    keys = np.concatenate([fwd, np.sort(dst * np.int64(n) + src)])
-    order = np.argsort(keys, kind="stable")
-    keys = keys[order]
-    first = np.ones(keys.size, bool)
-    first[1:] = keys[1:] != keys[:-1]
-    direction = np.bincount(np.cumsum(first) - 1,
-                            weights=np.where(order < fwd.size, 1, 2))
-    usrc, udst = np.divmod(keys[first], np.int64(n))
-    nbr_ptr = np.zeros(n + 1, np.int64)
-    nbr_ptr[1:] = np.cumsum(np.bincount(usrc, minlength=n))
-    nbr_ptr, nbr_idx = nbr_ptr.astype(np.int32), udst.astype(np.int32)
-    nbr_code = (4 * udst + direction.astype(np.int64)).astype(np.int32)
-    deg = (nbr_ptr[1:] - nbr_ptr[:-1]).astype(np.int32)
+    # every arc seen from its source (4·key + 1, out) and from its target
+    # (4·reverse key + 2, in), sorted together.  A mutual pair gives
+    # 4k + 1 and 4k + 2 side by side, the only adjacent entries that
+    # differ by 1, and folds into 4k + 3.  An entry 4·(x·n + w) + dir is
+    # x·4n + (4·w + dir), so one divmod by 4n splits row from code.
+    rev = out_idx * n64 + src
+    tagged = np.concatenate([4 * key.view(np.uint64) + 1,
+                             4 * rev.view(np.uint64) + 2])
+    tagged.sort()
+    pair = np.flatnonzero(np.diff(tagged) == 1)
+    tagged[pair] += 2
+    rows, code = np.divmod(np.delete(tagged, pair + 1), np.uint64(4 * n))
+    out_ptr, nbr_ptr = _row_ptr(src, n), _row_ptr(rows.view(np.int64), n)
+    deg = nbr_ptr[1:] - nbr_ptr[:-1]
     out_deg = out_ptr[1:] - out_ptr[:-1]
-    arrays = GraphArrays(out_ptr=out_ptr, out_idx=out_idx, nbr_ptr=nbr_ptr,
-                         nbr_idx=nbr_idx, nbr_deg=deg, nbr_code=nbr_code)
-    return (arrays, int(src.size), int(usrc.size),
+    arrays = GraphArrays(out_ptr=out_ptr, out_idx=out_idx.astype(np.int32),
+                         nbr_ptr=nbr_ptr, nbr_idx=(code >> 2).astype(np.int32),
+                         nbr_deg=deg, nbr_code=code.astype(np.int32))
+    return (arrays, int(key.size), int(code.size),
             int(deg.max()) if n and deg.size else 0,
             int(out_deg.max()) if n and out_deg.size else 0)
 
